@@ -22,6 +22,9 @@ SUITES = (
 
 def main() -> None:
     import importlib
+
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     only = sys.argv[1] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")
     failures = 0

@@ -161,9 +161,9 @@ def _space_of(aval) -> str:
 
 
 def _norm_shape(shape) -> Tuple[int, ...]:
-    # BlockSpec dims mapped away appear as a non-int sentinel; they window
-    # a single element
-    return tuple(int(b) if isinstance(b, int) else 1 for b in shape)
+    # each dim is a ``Blocked(block_size)``; a squeezed dim (``None`` in the
+    # BlockSpec) has no block size and windows a single element
+    return tuple(int(getattr(b, "block_size", 1)) for b in shape)
 
 
 def _index_map_fn(closed) -> Callable:
@@ -191,8 +191,8 @@ def _captures_from_jaxpr(jaxpr, out: List[PallasCapture]) -> None:
 def _capture_from_eqn(eqn) -> PallasCapture:
     gm = eqn.params["grid_mapping"]
     grid = tuple(int(g) for g in gm.grid)
-    cp = eqn.params.get("compiler_params") or {}
-    sem = (cp.get("mosaic") or {}).get("dimension_semantics")
+    cp = (eqn.params.get("compiler_params") or {}).get("mosaic_tpu")
+    sem = getattr(cp, "dimension_semantics", None)
     sem = tuple(sem) if sem is not None else None
     body = eqn.params["jaxpr"]
 
@@ -202,7 +202,7 @@ def _capture_from_eqn(eqn) -> PallasCapture:
         kind = "input" if i < n_in else "output"
         origin = getattr(bm, "origin", "") or (
             f"args[{i}]" if kind == "input" else f"outputs[{i - n_in}]")
-        sd = bm.array_shape_dtype
+        sd = bm.array_aval
         refs.append(RefInfo(
             name=str(origin), kind=kind,
             space=_space_of(bm.transformed_block_aval),

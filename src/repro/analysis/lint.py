@@ -629,13 +629,13 @@ def _check_pallas_semantics(tree: ast.Module, index: _ModuleIndex, path: str,
                 "pl.pallas_call without compiler_params: pass explicit "
                 "dimension_semantics (Megacore partitioning corrupts "
                 "grid-carried state under the silent 'parallel' default)"))
-        elif tail == "TPUCompilerParams":
+        elif tail == "CompilerParams":
             if not any(kw.arg == "dimension_semantics"
                        for kw in node.keywords):
                 out.append(Violation(
                     "pallas-dim-semantics", path, node.lineno,
                     node.col_offset,
-                    "TPUCompilerParams without dimension_semantics"))
+                    "CompilerParams without dimension_semantics"))
         elif tail and tail.endswith("compiler_params") and tail != \
                 "compiler_params":
             # helper wrappers (e.g. _tpu_compiler_params): a bare zero-

@@ -137,11 +137,15 @@ def make_sent140_like(num_clients: int = 200, vocab: int = 2000, seq_len: int = 
         boost[topic] += 3.0
         p = pop * np.exp(boost * 0.2)
         p /= p.sum()
+        # the draws rng.choice(vocab, size, p=p) makes, with its O(vocab)
+        # cdf built once per client instead of once per sentence
+        cdf = p.cumsum()
+        cdf /= cdf[-1]
         lens = rng.integers(6, seq_len + 1, n)
         seqs = np.full((n, seq_len), -1, np.int32)
         lab = np.zeros(n, np.int32)
         for j in range(n):
-            s = rng.choice(vocab, size=lens[j], p=p)
+            s = cdf.searchsorted(rng.random(lens[j]), side="right")
             seqs[j, : lens[j]] = s
             score = sentiment[s].mean() + rng.normal(0, 0.3)
             lab[j] = int(score > 0)

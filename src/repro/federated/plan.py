@@ -942,7 +942,7 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
     # ---- cohort-sharded execution (plan.sharding) -------------------------
     sharding = plan.sharding
     if sharding is not None:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh, s_axis = sharding.mesh, sharding.axis
@@ -1141,13 +1141,13 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
                     fn = shard_map(
                         lambda p, d, w, c: body(p, d, None, w, c), mesh=mesh,
                         in_specs=(P(), dspec, P(s_axis), P()),
-                        out_specs=ospecs, check_rep=False)
+                        out_specs=ospecs, check_vma=False)
                     res = fn(params, data, wmask, counts)
                 else:
                     fn = shard_map(
                         body, mesh=mesh,
                         in_specs=(P(), dspec, P(s_axis), P(s_axis), P()),
-                        out_specs=ospecs, check_rep=False)
+                        out_specs=ospecs, check_vma=False)
                     res = fn(params, data, sub_ids, wmask, counts)
                 agg, loss, sub_rows = res[:3]
                 return agg, loss, sub_rows, k_real, (res[3] if telemetry
@@ -1182,12 +1182,12 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
                 fn = shard_map(
                     lambda p, d, c: _flat_shard_body(p, d, None, c),
                     mesh=mesh, in_specs=(P(), dspec, P()),
-                    out_specs=ospecs, check_rep=False)
+                    out_specs=ospecs, check_vma=False)
                 res = fn(params, data, counts)
             else:
                 fn = shard_map(_flat_shard_body, mesh=mesh,
                                in_specs=(P(), dspec, P(), P()),
-                               out_specs=ospecs, check_rep=False)
+                               out_specs=ospecs, check_vma=False)
                 res = fn(params, data, sub_ids, counts)
             agg, loss, sub_rows = res[:3]
             return agg, loss, sub_rows, None, (res[3] if telemetry else None)

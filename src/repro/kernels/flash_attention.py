@@ -122,10 +122,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if not interpret:
         # (batch*head, q-block) axes write disjoint output tiles; the
         # k-block axis carries (m, l, acc) scratch and must stay sequential
-        cp = _tpu_compiler_params(
+        kwargs["compiler_params"] = _tpu_compiler_params(
             semantics=("parallel", "parallel", "arbitrary"))
-        if cp is not None:
-            kwargs["compiler_params"] = cp
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, causal=causal, window=window,
                           blk_q=blk_q, blk_k=blk_k, nk=nk),

@@ -110,9 +110,8 @@ def flash_decode(q, k_cache, v_cache, k_positions, q_position, *, window: int = 
     if not interpret:
         # the (batch*head) axis writes disjoint outputs; the cache-block
         # axis carries (m, l, acc) scratch and must stay sequential
-        cp = _tpu_compiler_params(semantics=("parallel", "arbitrary"))
-        if cp is not None:
-            kwargs["compiler_params"] = cp
+        kwargs["compiler_params"] = _tpu_compiler_params(
+            semantics=("parallel", "arbitrary"))
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, window=window, blk_s=blk_s, ns=ns),
         grid=(b * h, ns),

@@ -34,8 +34,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.common.hw import HW
 
-DEFAULT_V_BLK = 512
+DEFAULT_V_BLK = 1024
 DEFAULT_T_BLK = 1024
+
+#: XLA tiles a 1-D 32-bit array in chunks of 1024 elements, and Mosaic
+#: refuses a 1-D block that does not match that tiling. Every compiled
+#: kernel here streams its ids/heat as 1-D blocks, so those blocks are
+#: multiples of this tile and the arrays are padded to them.
+TILE_1D = 1024
 
 #: VMEM budget (bytes) a kernel's per-program working set must fit for the
 #: compiled path: the per-core capacity from ``repro.common.hw`` minus 1/4
@@ -60,7 +66,9 @@ def _kernel(params_ref, ids_ref, rows_ref, heat_ref, out_ref, *,
     # padding ids (-1) are < 0 and match no vocab row in any tile
     onehot = (vrows == ids[None, :]).astype(jnp.float32)  # (V_BLK, T_BLK)
     rows = rows_ref[...].astype(jnp.float32)             # (T_BLK, D)
-    out_ref[...] += jnp.dot(onehot, rows, preferred_element_type=jnp.float32)
+    # HIGHEST keeps the accumulation in true f32 on TPU, as in union_segsum
+    out_ref[...] += jnp.dot(onehot, rows, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(it == nt - 1)
     def _finalize():
@@ -71,23 +79,32 @@ def _kernel(params_ref, ids_ref, rows_ref, heat_ref, out_ref, *,
         out_ref[...] *= factor[:, None]
 
 
-def _pick_blk(dim: int, blk: int) -> int:
-    """Largest power-of-two block <= min(blk, dim)."""
-    b = 1
-    while b * 2 <= min(blk, dim):
-        b *= 2
-    return b
+def _fit_blk(dim, blk: int) -> int:
+    """``blk`` clamped to ``dim`` rounded up to ``TILE_1D``.
+
+    A block never outgrows its tile-padded array, and is never shrunk below
+    the tile: a small ``dim`` is padded up to the block instead, so the
+    block keeps matching XLA's 1-D tiling at every size.
+    """
+    if dim is None or dim <= 0:
+        return blk
+    return min(blk, -(-dim // TILE_1D) * TILE_1D)
+
+
+def _check_tiled(*blocks: int) -> None:
+    """Refuse compiled-path blocks that Mosaic would reject at lowering."""
+    bad = [b for b in blocks if b % TILE_1D]
+    if bad:
+        raise ValueError(
+            f"compiled Pallas blocks {bad} are not multiples of the 1-D tile "
+            f"{TILE_1D}; smaller blocks run only in interpret mode")
 
 
 def _block_sizes(vocab, t, v_blk: int, t_blk: int):
     """The (v_blk, t_blk) the kernel actually runs with — the single source
     of the block adjustments, shared by ``rowsparse_scatter``, its
     ``fits_vmem`` guard, and the static auditor so they cannot drift."""
-    if vocab is not None:
-        v_blk = _pick_blk(vocab, v_blk)
-    if t is not None and t > 0:
-        t_blk = min(t_blk, t)
-    return v_blk, t_blk
+    return _fit_blk(vocab, v_blk), _fit_blk(t, t_blk)
 
 
 def vmem_footprint(row_elems: int, *, vocab: int | None = None,
@@ -121,7 +138,7 @@ def on_tpu() -> bool:
 
 
 def _tpu_compiler_params(semantics=("parallel", "arbitrary")):
-    """Mosaic params for the compiled path; None when unavailable.
+    """Mosaic params for the compiled path.
 
     ``semantics`` declares one entry per grid dim. ``heat_scatter``'s vocab
     axis is safe to split across cores ('parallel': its vocab blocks touch
@@ -129,10 +146,7 @@ def _tpu_compiler_params(semantics=("parallel", "arbitrary")):
     (e.g. ``union_segsum``'s SMEM union offset) must declare that dim
     'arbitrary' or Megacore partitioning will corrupt it.
     """
-    try:
-        return pltpu.TPUCompilerParams(dimension_semantics=tuple(semantics))
-    except Exception:  # pragma: no cover — jax build without TPUCompilerParams
-        return None
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
 
 
 def rowsparse_scatter(ids, rows, heat, total: float, vocab: int, *,
@@ -175,11 +189,11 @@ def rowsparse_scatter(ids, rows, heat, total: float, vocab: int, *,
 
     kwargs = {}
     if not interpret:
+        _check_tiled(v_blk, t_blk)
         # vocab grid axis: disjoint output rows per block, Megacore-safe to
         # split; row axis: sequential accumulation into out_ref
-        cp = _tpu_compiler_params(semantics=("parallel", "arbitrary"))
-        if cp is not None:
-            kwargs["compiler_params"] = cp
+        kwargs["compiler_params"] = _tpu_compiler_params(
+            semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         functools.partial(_kernel, v_blk=v_blk, t_blk=t_blk, nt=nt),
         grid=(nv, nt),

@@ -64,7 +64,7 @@ class KernelEntry:
 
 # -- union_segsum -----------------------------------------------------------
 # 16 clients x 656 ids over a 64k vocab, D=64, union capacity 8192: both
-# grid dims >1 (nv=128, nt=21) and the row count is deliberately NOT a
+# grid dims >1 (nv=64, nt=11) and the row count is deliberately NOT a
 # multiple of t_blk so the wrapper's padding path is part of the trace.
 _US = dict(V=65536, K=16, R=656, D=64, CAP=8192)
 
@@ -99,7 +99,7 @@ def _guard_union_segsum() -> GuardReport:
 
 
 # -- rowsparse_scatter ------------------------------------------------------
-# 8192 rows into a 64k vocab at D=64: grid (nv=128, nt=8).
+# 8192 rows into a 64k vocab at D=64: grid (nv=64, nt=8).
 _HS = dict(V=65536, T=8192, D=64)
 
 

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash_attention
 from repro.kernels.flash_decode import flash_decode as _flash_decode
+from repro.kernels.heat_scatter import TILE_1D
 from repro.kernels.heat_scatter import heat_scatter as _heat_scatter
 from repro.kernels.heat_scatter import on_tpu as _on_tpu
 from repro.kernels.heat_scatter import rowsparse_scatter as _rowsparse_scatter
@@ -22,14 +23,14 @@ from repro.kernels.union_segsum import union_segsum as _union_segsum
 
 @functools.partial(jax.jit, static_argnames=("vocab", "v_blk", "t_blk"))
 def heat_scatter(ids, grads, heat, total, vocab: int,
-                 v_blk: int = 512, t_blk: int = 1024):
+                 v_blk: int = TILE_1D, t_blk: int = TILE_1D):
     return _heat_scatter(ids, grads, heat, total, vocab, v_blk=v_blk, t_blk=t_blk,
                          interpret=not _on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("vocab", "v_blk", "t_blk"))
 def rowsparse_scatter(ids, rows, heat, total, vocab: int,
-                      scale=1.0, v_blk: int = 512, t_blk: int = 1024):
+                      scale=1.0, v_blk: int = TILE_1D, t_blk: int = TILE_1D):
     """Fused cohort row-sparse aggregation + heat correction (see kernel).
 
     As with ``union_segsum``, ``total``/``scale`` are traced scalar
@@ -41,7 +42,7 @@ def rowsparse_scatter(ids, rows, heat, total, vocab: int,
 
 @functools.partial(jax.jit, static_argnames=("cap", "num_rows", "v_blk", "t_blk"))
 def union_segsum(ids, rows, heat, total, cap: int, num_rows: int,
-                 scale=1.0, v_blk: int = 512, t_blk: int = 512):
+                 scale=1.0, v_blk: int = TILE_1D, t_blk: int = TILE_1D):
     """Fused union + segment-sum + heat scaling (see kernel module).
 
     ``total`` and ``scale`` are traced scalar operands — varying them (e.g.

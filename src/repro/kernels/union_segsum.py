@@ -17,8 +17,9 @@ TPU (row-major), with the vocab axis outer. Per vocab block the kernel
    row blocks (same scheme as ``heat_scatter``), together with per-row match
    counts;
 2. on the block's last row tile, applies the fused heat factor, ranks the
-   touched rows with an in-block cumsum, compacts them to the front of the
-   block through a ``(v_blk, v_blk)`` permutation matmul, and
+   touched rows with an in-block prefix count (a 0/1 triangular matmul on
+   the MXU — Mosaic has no cumsum), compacts them to the front of the block
+   through a ``(v_blk, v_blk)`` permutation matmul, and
 3. appends the compacted ``(ids, rows)`` window to the output at the running
    union offset (an SMEM carry across vocab blocks) with a dynamic store.
 
@@ -44,11 +45,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.heat_scatter import (VMEM_BUDGET, _pick_blk,
-                                        _tpu_compiler_params, on_tpu)
+from repro.kernels.heat_scatter import (TILE_1D, VMEM_BUDGET, _check_tiled,
+                                        _fit_blk, _tpu_compiler_params, on_tpu)
 
-DEFAULT_V_BLK = 512
-DEFAULT_T_BLK = 512
+DEFAULT_V_BLK = TILE_1D
+DEFAULT_T_BLK = TILE_1D
 
 __all__ = ["union_segsum", "fits_vmem", "vmem_footprint", "VMEM_BUDGET"]
 
@@ -102,12 +103,23 @@ def _kernel(params_ref, ids_ref, rows_ref, heat_ref, out_ids_ref, out_rows_ref,
         else:
             factor = jnp.broadcast_to(scale, (v_blk,)).astype(jnp.float32)
         scaled = acc_ref[...] * factor[:, None]
-        rank = jnp.cumsum(touched.astype(jnp.int32)) - 1   # in-block rank
+        t_row = touched.astype(jnp.float32)[None, :]       # (1, v_blk)
+        # in-block rank: rank[v] = #touched u <= v, minus one — a matmul with
+        # the 0/1 upper triangle; exact in bf16 inputs with f32 accumulation.
+        # DEFAULT is stated: a caller's "highest" default would ask Mosaic
+        # for an fp32 contraction of bf16 operands, which it refuses
+        upper = (jax.lax.broadcasted_iota(jnp.int32, (v_blk, v_blk), 0)
+                 <= jax.lax.broadcasted_iota(jnp.int32, (v_blk, v_blk), 1))
+        rank = jnp.dot(t_row.astype(jnp.bfloat16),
+                       upper.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.DEFAULT
+                       ).astype(jnp.int32) - 1             # (1, v_blk)
         n_new = jnp.sum(touched.astype(jnp.int32))
         # compact the touched rows to the window front: P[s, v] = 1 iff the
         # touched vocab row v has rank s — a permutation matmul on the MXU
         srange = jax.lax.broadcasted_iota(jnp.int32, (v_blk, v_blk), 0)
-        sel = (srange == rank[None, :]) & touched[None, :]   # (slot, vocab)
+        sel = (srange == rank) & (t_row > 0)               # (slot, vocab)
         win_rows = jnp.dot(sel.astype(jnp.float32), scaled,
                            preferred_element_type=jnp.float32,
                            precision=jax.lax.Precision.HIGHEST)
@@ -122,8 +134,8 @@ def _kernel(params_ref, ids_ref, rows_ref, heat_ref, out_ids_ref, out_rows_ref,
         # clamp: once the union overflows cap, windows land in the padding
         # tail [cap, cap + v_blk) and are sliced off by the wrapper
         offset = jnp.minimum(carry, cap)
-        pl.store(out_ids_ref, (pl.ds(offset, v_blk), slice(None)), win_ids)
-        pl.store(out_rows_ref, (pl.ds(offset, v_blk), slice(None)), win_rows)
+        out_ids_ref[pl.ds(offset, v_blk), :] = win_ids
+        out_rows_ref[pl.ds(offset, v_blk), :] = win_rows
         carry_ref[0] = carry + n_new
 
 
@@ -131,11 +143,7 @@ def _block_sizes(num_rows, t, v_blk: int, t_blk: int):
     """The (v_blk, t_blk) the kernel actually runs with — the single source
     of the block adjustments, shared by ``union_segsum`` and ``fits_vmem``
     so the ``"auto"`` budget guard and the kernel never drift apart."""
-    if num_rows is not None:
-        v_blk = _pick_blk(num_rows, v_blk)
-    if t is not None and t > 0:
-        t_blk = min(t_blk, t)
-    return v_blk, t_blk
+    return _fit_blk(num_rows, v_blk), _fit_blk(t, t_blk)
 
 
 def vmem_footprint(cap: int, row_elems: int, *, num_rows: int | None = None,
@@ -221,9 +229,9 @@ def union_segsum(ids, rows, heat, total: float, cap: int, num_rows: int, *,
 
     kwargs = {}
     if not interpret:
-        cp = _tpu_compiler_params(semantics=_DIM_SEMANTICS)
-        if cp is not None:
-            kwargs["compiler_params"] = cp
+        _check_tiled(v_blk, t_blk)
+        kwargs["compiler_params"] = _tpu_compiler_params(
+            semantics=_DIM_SEMANTICS)
     out_ids, out_rows = pl.pallas_call(
         functools.partial(_kernel, use_heat=use_heat, v_blk=v_blk, t_blk=t_blk,
                           nt=nt, cap=cap),

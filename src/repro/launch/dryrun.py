@@ -38,7 +38,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, SHAPES, FedConfig, get_config
 from repro.federated.simulation import make_round_step
-from repro.launch.hlo import analyze_hlo, cost_analysis_dict
+from repro.launch.hlo import analyze_hlo
 from repro.launch.mesh import make_production_mesh
 from repro.launch.shardings import (shard_batch_sds, shard_cache_sds,
                                     shard_params_sds)
@@ -171,7 +171,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool = False,
                   "temp_size_in_bytes", "generated_code_size_in_bytes",
                   "alias_size_in_bytes"):
             mem_info[k] = int(getattr(mem, k, 0) or 0)
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo_text = compiled.as_text()
         col = analyze_hlo(hlo_text)
         result = dict(

@@ -59,14 +59,6 @@ _RG_RE = re.compile(
     r"|\[[0-9,]+\]<=\[[0-9,]+\](?:T\([0-9,]+\))?)")
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jaxlib versions (dict vs [dict])."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def _shape_bytes_list(text: str) -> List[int]:
     """Byte sizes of every typed shape literal in a string, in order."""
     out = []

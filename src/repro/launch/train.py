@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import save_checkpoint
+from repro.common.compile_cache import use_compile_cache
 from repro.configs import FedConfig, get_config, get_smoke_config
 from repro.data import make_lm_federated
 from repro.federated import make_round_step
@@ -54,6 +55,7 @@ def main():
     ap.add_argument("--algorithm", default="fedsubavg")
     ap.add_argument("--ckpt", default="")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if SCALES[args.scale]:

@@ -12,7 +12,7 @@ This file is an AST-only lint fixture: it is never imported or executed,
 so the imports need not resolve.
 """
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.sparse.aggregate import (aggregate_rowsparse,
                                     aggregate_rowsparse_partial,
@@ -31,7 +31,7 @@ def good_shard_body(stacked, heat, total):
 
 def run(mesh, stacked, heat, total):
     bad = shard_map(bad_shard_body, mesh=mesh, in_specs=None, out_specs=None,
-                    check_rep=False)
+                    check_vma=False)
     good = shard_map(good_shard_body, mesh=mesh, in_specs=None,
-                     out_specs=None, check_rep=False)
+                     out_specs=None, check_vma=False)
     return bad(stacked, heat, total), good(stacked, heat, total)

@@ -11,7 +11,7 @@ so the imports need not resolve.
 """
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def bad_loss_body(losses):
@@ -26,7 +26,7 @@ def good_loss_body(losses):
 
 def run(mesh, losses):
     bad = shard_map(bad_loss_body, mesh=mesh, in_specs=None, out_specs=None,
-                    check_rep=False)
+                    check_vma=False)
     good = shard_map(good_loss_body, mesh=mesh, in_specs=None,
-                     out_specs=None, check_rep=False)
+                     out_specs=None, check_vma=False)
     return bad(losses), good(losses)

@@ -51,6 +51,21 @@ def test_seeded_violation_fires(fixture, rule):
     assert any(abs(violations[0].line - m) <= 2 for m in marked)
 
 
+def test_compiler_params_without_semantics_fires():
+    """``pltpu.CompilerParams`` must state the grid's semantics too."""
+    src = textwrap.dedent("""
+        from jax.experimental.pallas import tpu as pltpu
+
+        BARE = pltpu.CompilerParams(vmem_limit_bytes=1 << 20)
+        OK = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+    """)
+    violations, _ = lint.lint_source(src, "mod.py")
+    assert [(v.rule, v.line) for v in violations] == [
+        ("pallas-dim-semantics", 4)]
+    assert "CompilerParams without dimension_semantics" in \
+        violations[0].message
+
+
 def test_cli_nonzero_on_fixtures_zero_on_clean(tmp_path):
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
     bad = subprocess.run(

@@ -60,3 +60,32 @@ def test_dispersion_grows_with_zipf_exponent():
     lo = make_movielens_like(num_clients=150, num_items=100, zipf_a=0.6, seed=3)
     hi = make_movielens_like(num_clients=150, num_items=100, zipf_a=1.8, seed=3)
     assert hi.heat.dispersion() >= lo.heat.dispersion()
+
+
+def test_sent140_draws_match_per_sentence_choice():
+    """The per-client CDF reproduces ``rng.choice(vocab, size, p=p)`` draw
+    for draw: the generator's old per-sentence loop, kept here as the
+    reference, yields the identical corpus."""
+    num_clients, vocab, seq_len, mean_samples, zipf_a = 6, 700, 24, 30, 1.1
+    rng = np.random.default_rng(5)
+    p0 = 1.0 / np.arange(1, vocab + 1) ** zipf_a
+    pop = p0 / p0.sum()
+    rng.normal(0, 1.0, vocab)                  # the planted polarities
+    toks = []
+    for _ in range(num_clients):
+        n = max(5, int(rng.poisson(mean_samples)))
+        boost = np.zeros(vocab)
+        boost[rng.choice(vocab, size=20, p=pop)] += 3.0
+        p = pop * np.exp(boost * 0.2)
+        p /= p.sum()
+        lens = rng.integers(6, seq_len + 1, n)
+        seqs = np.full((n, seq_len), -1, np.int32)
+        for j in range(n):
+            s = rng.choice(vocab, size=lens[j], p=p)
+            seqs[j, : lens[j]] = s
+            rng.normal(0, 0.3)                 # the label's noise draw
+        toks.append(seqs[max(1, int(n * 0.2)):])
+    ds = make_sent140_like(num_clients=num_clients, vocab=vocab, seed=5)
+    for c, want in enumerate(toks):
+        np.testing.assert_array_equal(ds.client_data["tokens"][c, :len(want)],
+                                      want)

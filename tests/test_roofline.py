@@ -13,7 +13,6 @@ import jax.numpy as jnp
 
 from benchmarks.roofline import analytic_flops_for
 from repro.configs import get_smoke_config
-from repro.launch.hlo import cost_analysis_dict
 from repro.models import build_model
 
 
@@ -25,9 +24,9 @@ def test_cost_analysis_counts_loop_body_once():
 
     x = jnp.zeros((64, 64))
     w = jnp.zeros((64, 64))
-    flops_scan = cost_analysis_dict(jax.jit(f).lower(x, w).compile())["flops"]
-    flops_once = cost_analysis_dict(
-        jax.jit(lambda x, w: x @ w).lower(x, w).compile())["flops"]
+    flops_scan = jax.jit(f).lower(x, w).compile().cost_analysis()["flops"]
+    flops_once = jax.jit(
+        lambda x, w: x @ w).lower(x, w).compile().cost_analysis()["flops"]
     assert flops_scan < 2 * flops_once  # NOT ~10x: body counted once
 
 
@@ -42,7 +41,7 @@ def test_analytic_flops_match_hlo_single_layer(arch):
     batch = {"tokens": jnp.ones((b, s), jnp.int32),
              "labels": jnp.ones((b, s), jnp.int32),
              "mask": jnp.ones((b, s), jnp.float32)}
-    hlo = cost_analysis_dict(jax.jit(api.loss).lower(params, batch).compile())["flops"]
+    hlo = jax.jit(api.loss).lower(params, batch).compile().cost_analysis()["flops"]
     af = analytic_flops_for(cfg, "prefill", b, s)   # forward-only loss
     # loss() is forward only here (no grad), so compare to the prefill estimate
     ratio = hlo / af["total"]
@@ -111,3 +110,15 @@ def test_hardware_constants_single_sourced():
     # the kernel VMEM budgets derive from the same source
     from repro.kernels.heat_scatter import VMEM_BUDGET
     assert VMEM_BUDGET == 3 * HW["vmem_bytes"] // 4
+
+
+def test_hardware_table_keyed_by_device_kind():
+    """``HW`` is the v5e row of the device-kind table, and a kind the table
+    does not list raises instead of borrowing another chip's peaks."""
+    from repro.common.hw import CHIPS, HW, TARGET_KIND, chip
+
+    assert chip("TPU v5 lite") is HW is CHIPS[TARGET_KIND]
+    assert HW["peak_flops_bf16"] == 197e12 and HW["hbm_bandwidth"] == 819e9
+    for kind in ("cpu", "TPU v4", "TPU v6 lite"):
+        with pytest.raises(KeyError, match="no hardware table"):
+            chip(kind)

@@ -280,16 +280,28 @@ def test_union_backend_auto_selection(monkeypatch):
 
 
 def test_fits_vmem_uses_actual_block_sizes():
-    """Regression: the budget guard mirrors the kernel's own block
-    adjustments (pow2-shrunk ``v_blk``, ``t_blk`` clamped to the element
-    count), so a small cohort/feature space can fit the budget where the
-    default-block estimate would refuse it."""
-    from repro.kernels.union_segsum import _pick_blk, fits_vmem
-    cap, d = 1024, 1024
-    assert not fits_vmem(cap, d)                      # default 512-blocks
-    assert fits_vmem(cap, d, num_rows=64, t=64)       # kernel-shrunk blocks
-    # the adjustment matches the kernel's: _pick_blk on v, min-clamp on t
-    assert _pick_blk(64, 512) == 64
+    """Regression: the budget guard prices the blocks the kernel runs with.
+    A block is clamped to its array rounded up to the 1-D tile (a 4096
+    block over 64 rows runs as 1024), and never shrunk below the tile —
+    a small cohort or feature space is padded up to the block instead, so
+    the block keeps matching XLA's 1-D tiling on TPU."""
+    from repro.kernels.union_segsum import (TILE_1D, _block_sizes, fits_vmem,
+                                            union_segsum, vmem_footprint)
+    cap, d = 1024, 64
+    assert _block_sizes(64, 64, 4096, 4096) == (TILE_1D, TILE_1D)
+    assert not fits_vmem(cap, d, v_blk=4096, t_blk=4096)
+    assert fits_vmem(cap, d, num_rows=64, t=64, v_blk=4096, t_blk=4096)
+    # the default blocks are the tile: never shrunk at small V or T
+    assert _block_sizes(64, 64, TILE_1D, TILE_1D) == (TILE_1D, TILE_1D)
+    assert (vmem_footprint(cap, d, num_rows=64, t=64)
+            == vmem_footprint(cap, d))
+    # large extents keep the requested blocks
+    assert _block_sizes(1 << 20, 1 << 15, 4096, 2048) == (4096, 2048)
+    # blocks below the tile run in interpret mode only: the compiled path
+    # refuses them instead of handing Mosaic a mismatched layout
+    with pytest.raises(ValueError, match="1-D tile"):
+        union_segsum(jnp.zeros((8,), jnp.int32), jnp.zeros((8, 2), jnp.float32),
+                     None, 1.0, 8, 64, v_blk=512, t_blk=512, interpret=False)
 
 
 def test_union_segsum_grid_dims_sequential(monkeypatch):
@@ -302,26 +314,28 @@ def test_union_segsum_grid_dims_sequential(monkeypatch):
     us_mod = importlib.import_module("repro.kernels.union_segsum")
     assert us_mod._DIM_SEMANTICS == ("arbitrary", "arbitrary")
     cp = hs_mod._tpu_compiler_params(semantics=us_mod._DIM_SEMANTICS)
-    if cp is not None:
-        assert "parallel" not in tuple(cp.dimension_semantics)
+    assert isinstance(cp, us_mod.pltpu.CompilerParams)
+    assert tuple(cp.dimension_semantics) == ("arbitrary", "arbitrary")
     # heat_scatter's own default (independent vocab blocks) is unchanged
     cp_hs = hs_mod._tpu_compiler_params()
-    if cp_hs is not None:
-        assert tuple(cp_hs.dimension_semantics) == ("parallel", "arbitrary")
+    assert tuple(cp_hs.dimension_semantics) == ("parallel", "arbitrary")
 
     # and the compiled path actually requests those semantics: capture what
     # union_segsum hands to _tpu_compiler_params on interpret=False (the
     # kernel itself still executes via the interpreter on CPU)
     seen = {}
 
+    real_params = us_mod._tpu_compiler_params
+
     def fake_params(semantics=("parallel", "arbitrary")):
         seen["semantics"] = tuple(semantics)
-        return None
+        return real_params(semantics=semantics)
 
     real_call = us_mod.pl.pallas_call
 
     def interpreted_call(*args, **kw):
         seen["interpret"] = kw.get("interpret")
+        seen["compiler_params"] = kw.pop("compiler_params", None)
         kw["interpret"] = True
         return real_call(*args, **kw)
 
@@ -332,6 +346,8 @@ def test_union_segsum_grid_dims_sequential(monkeypatch):
     u, _ = us_mod.union_segsum(ids, rows, None, 4.0, 4, 8, interpret=False)
     assert seen["interpret"] is False
     assert seen["semantics"] == us_mod._DIM_SEMANTICS
+    assert tuple(seen["compiler_params"].dimension_semantics) == \
+        us_mod._DIM_SEMANTICS
     assert sorted(np.asarray(u)[np.asarray(u) >= 0].tolist()) == [0, 2]
 
 
