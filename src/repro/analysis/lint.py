@@ -256,6 +256,21 @@ def _flat_stmts(body: Sequence[ast.stmt]) -> Iterable[ast.stmt]:
             yield from _flat_stmts(handler.body)
 
 
+def _stmt_nodes(st: ast.stmt) -> Iterable[ast.AST]:
+    """Nodes of one statement of :func:`_flat_stmts`: a control-flow
+    statement's own header (a ``with`` item, an ``if`` test) without the
+    bodies that :func:`_flat_stmts` yields as statements of their own."""
+    if isinstance(st, (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)):
+        yield from ast.walk(st)
+        return
+    for name, value in ast.iter_fields(st):
+        if name in ("body", "orelse", "finalbody", "handlers"):
+            continue
+        for v in value if isinstance(value, list) else [value]:
+            if isinstance(v, ast.AST):
+                yield from ast.walk(v)
+
+
 def _uses_tracer_namespace(fn: ast.AST) -> bool:
     """Does the function's own scope touch ``jnp.*`` / ``lax.*``?
 
@@ -785,7 +800,7 @@ def _check_donated_reuse(tree: ast.Module, index: _ModuleIndex, path: str,
         active: Dict[str, Tuple[int, int]] = {}   # dotted name -> call pos
         for st in _flat_stmts(info.node.body):
             if active:
-                for n in ast.walk(st):
+                for n in _stmt_nodes(st):
                     if isinstance(n, (ast.Name, ast.Attribute)) \
                             and isinstance(getattr(n, "ctx", None), ast.Load):
                         d = _dotted(n)
@@ -805,7 +820,7 @@ def _check_donated_reuse(tree: ast.Module, index: _ModuleIndex, path: str,
                 targets.extend(_target_names(st.target))
             for name in targets:
                 active.pop(name, None)
-            for n in ast.walk(st):
+            for n in _stmt_nodes(st):
                 if isinstance(n, ast.Call):
                     callee = _dotted(n.func)
                     if callee in donated:

@@ -86,6 +86,7 @@ from repro.sparse.rowsparse import (RowSparse, count_unique_ids, is_rowsparse,
 from repro.telemetry.round import (HEAT_BUCKETS, RoundTelemetry, drop_stats,
                                    heat_histogram, tree_agg_rows, tree_sq_sum,
                                    union_ids_vec)
+from repro.telemetry.spans import AGGREGATE, APPLY, LOCAL, LOSS, TELEMETRY
 
 Array = jax.Array
 
@@ -658,6 +659,14 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
     produces (no extra PRNG draws, no change to losses or parameters), so it
     stacks along the scan axis under a multi-round ``lax.scan`` engine and
     crosses ``shard_map`` boundaries via psums/all-gathers.
+
+    Each phase of the step runs under a ``jax.named_scope``
+    (``repro.telemetry.spans``), so its device operations carry the phase
+    in their HLO metadata: ``fedsub.local`` (local training),
+    ``fedsub.aggregate`` (compression, union and segment-sum, the
+    cross-shard combine), ``fedsub.apply``, ``fedsub.loss`` (the monitoring
+    forward pass) and ``fedsub.telemetry`` (``sub_rows``/``density`` and
+    the telemetry). Scopes are metadata: no number changes.
     """
     local, transport, server = plan.local, plan.transport, plan.server
     feature_keys = tuple(plan.feature_keys)
@@ -778,6 +787,14 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
     # ---- telemetry (in-jit observability; pure reads of existing values) --
     heat_space = paths[0][1][0] if paths else None
 
+    def _sq(tree) -> Optional[Array]:
+        """The telemetry's sum of squares of ``tree`` (``None`` when off)."""
+        if not telemetry:
+            return None
+        with jax.named_scope(TELEMETRY):
+            return tree_sq_sum(tree)
+
+    @jax.named_scope(TELEMETRY)
     def _cohort_drop_tel(data: Dict, used_ids: Optional[Array]):
         """``(union ids, dropped, mass, per_client)`` from the round's ids.
 
@@ -799,6 +816,7 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
                                    used_ids, vocab)
         return used_ids, dropped.astype(jnp.int32), mass, None
 
+    @jax.named_scope(TELEMETRY)
     def _assemble_tel(union, dropped, mass, per_client, agg, counts,
                       pre_sq, post_sq, shard_union_sizes=None):
         union_size = ((union >= 0).sum(dtype=jnp.int32)
@@ -910,8 +928,10 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
 
     else:
         raise TypeError(f"unknown LocalStep: {local!r}")
+    run_local = jax.named_scope(LOCAL)(run_local)
 
     # ---- server apply (shared by the single-device and sharded paths) -----
+    @jax.named_scope(APPLY)
     def apply_sparse(state, agg):
         """Apply an aggregated sparse-plane update (RowSparse or dense leaves,
         correction already fused)."""
@@ -926,6 +946,7 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
         dense = boxed_like(decode_delta_tree(agg), state.params)
         return server_alg.apply(state, dense)
 
+    @jax.named_scope(APPLY)
     def apply_dense(state, update, counts):
         """Apply a dense-transport cohort-mean update (correction pending)."""
         if server_alg is not None:
@@ -982,59 +1003,65 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
             update, _, used_ids, data = run_local(params, data, sub_ids)
             _debug_check_ids(used_ids, data)  # checkify crosses shard_map
             raw = update
-            if sparse and transport.topk:
-                # per-client row selection shards exactly (no cohort state)
-                update = compress_delta_tree(update, topk=transport.topk)
-            update = _mask_clients(update, wmask)
-            scale = 1.0 / float(k_real)
+            with jax.named_scope(AGGREGATE):
+                if sparse and transport.topk:
+                    # per-client row selection shards exactly (no cohort
+                    # state)
+                    update = compress_delta_tree(update, topk=transport.topk)
+                update = _mask_clients(update, wmask)
+                scale = 1.0 / float(k_real)
 
-            if sparse:
-                def agg_leaf(leaf, space):
-                    if is_rowsparse(leaf):
-                        h = (counts.get(space[0])
-                             if server.correct and space is not None else None)
-                        part = aggregate_rowsparse_partial(
-                            leaf, union_backend=transport.union_backend)
-                        return combine_rowsparse_partials(
-                            part, s_axis, ndev, h, n_total, scale,
-                            combine=sharding.combine,
-                            union_backend=transport.union_backend)
-                    mean = jax.lax.psum(leaf.sum(axis=0), s_axis) * scale
-                    if server.correct:
-                        mean = correct_dense_leaf(mean, space, counts, n_total)
-                    return mean
+                if sparse:
+                    def agg_leaf(leaf, space):
+                        if is_rowsparse(leaf):
+                            h = (counts.get(space[0])
+                                 if server.correct and space is not None
+                                 else None)
+                            part = aggregate_rowsparse_partial(
+                                leaf, union_backend=transport.union_backend)
+                            return combine_rowsparse_partials(
+                                part, s_axis, ndev, h, n_total, scale,
+                                combine=sharding.combine,
+                                union_backend=transport.union_backend)
+                        mean = jax.lax.psum(leaf.sum(axis=0), s_axis) * scale
+                        if server.correct:
+                            mean = correct_dense_leaf(mean, space, counts,
+                                                      n_total)
+                        return mean
 
-                agg = jax.tree.map(
-                    agg_leaf, update, heat_spec.leaf_spaces,
-                    is_leaf=lambda x: x is None or is_rowsparse(x))
-            else:
-                if isinstance(local, SubmodelReplicatedLocal):
-                    update = _densify_stacked(update)
-                agg = jax.tree.map(
-                    lambda d: jax.lax.psum(d.sum(axis=0), s_axis) * scale,
-                    update)
+                    agg = jax.tree.map(
+                        agg_leaf, update, heat_spec.leaf_spaces,
+                        is_leaf=lambda x: x is None or is_rowsparse(x))
+                else:
+                    if isinstance(local, SubmodelReplicatedLocal):
+                        update = _densify_stacked(update)
+                    agg = jax.tree.map(
+                        lambda d: jax.lax.psum(d.sum(axis=0), s_axis) * scale,
+                        update)
 
-            first = jax.tree.map(lambda x: x[:, 0], data)
-            losses = jax.vmap(lambda b: loss_fn(params, b))(first)
-            loss = jax.lax.psum((losses * wmask).sum(), s_axis) / k_real
-            if sparse and used_ids is not None:
-                valid = (used_ids >= 0) & (wmask > 0)[:, None]
-                sub_rows = jax.lax.psum(valid.sum(), s_axis)
-            else:
-                sub_rows = jnp.zeros((), jnp.int32)
-            if not telemetry:
-                return agg, loss, sub_rows
-            # pre/post-compression norms over the REAL clients only (pad
-            # clients are cyclic repeats; masking keeps them out of both)
-            pre_sq = jax.lax.psum(tree_sq_sum(_mask_clients(raw, wmask)),
-                                  s_axis)
-            post_sq = jax.lax.psum(tree_sq_sum(update), s_axis)
-            tel = {"norm_pre_sq": pre_sq, "norm_post_sq": post_sq}
-            if sparse:
-                masked = jnp.where((wmask > 0)[:, None], used_ids, -1)
-                tel["used_ids"] = masked
-                tel["shard_union"] = count_unique_ids(masked)[None]
-            return agg, loss, sub_rows, tel
+            with jax.named_scope(LOSS):
+                first = jax.tree.map(lambda x: x[:, 0], data)
+                losses = jax.vmap(lambda b: loss_fn(params, b))(first)
+                loss = jax.lax.psum((losses * wmask).sum(), s_axis) / k_real
+            with jax.named_scope(TELEMETRY):
+                if sparse and used_ids is not None:
+                    valid = (used_ids >= 0) & (wmask > 0)[:, None]
+                    sub_rows = jax.lax.psum(valid.sum(), s_axis)
+                else:
+                    sub_rows = jnp.zeros((), jnp.int32)
+                if not telemetry:
+                    return agg, loss, sub_rows
+                # pre/post-compression norms over the REAL clients only (pad
+                # clients are cyclic repeats; masking keeps them out of both)
+                pre_sq = jax.lax.psum(tree_sq_sum(_mask_clients(raw, wmask)),
+                                      s_axis)
+                post_sq = jax.lax.psum(tree_sq_sum(update), s_axis)
+                tel = {"norm_pre_sq": pre_sq, "norm_post_sq": post_sq}
+                if sparse:
+                    masked = jnp.where((wmask > 0)[:, None], used_ids, -1)
+                    tel["used_ids"] = masked
+                    tel["shard_union"] = count_unique_ids(masked)[None]
+                return agg, loss, sub_rows, tel
 
         def _flat_shard_body(params, data, sub_ids, counts):
             """One shard's B/ndev examples of the pooled cohort batch.
@@ -1048,7 +1075,8 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
             """
             update, fwd_loss, used_ids, _ = run_local(params, data, sub_ids)
             _debug_check_ids(used_ids, data)  # checkify crosses shard_map
-            loss = jax.lax.pmean(fwd_loss, s_axis)
+            with jax.named_scope(LOSS):
+                loss = jax.lax.pmean(fwd_loss, s_axis)
             scale = 1.0 / float(ndev)
             if sparse:
                 def fix(leaf, space):
@@ -1064,31 +1092,37 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
                         leaf = correct_dense_leaf(leaf, space, counts, n_total)
                     return leaf
 
-                agg = jax.tree.map(
-                    fix, update, heat_spec.leaf_spaces,
-                    is_leaf=lambda x: x is None or is_rowsparse(x))
-                # the single-device union count: distinct ids across shards
-                sub_rows = count_unique_ids(
-                    jax.lax.all_gather(used_ids, s_axis))
+                with jax.named_scope(AGGREGATE):
+                    agg = jax.tree.map(
+                        fix, update, heat_spec.leaf_spaces,
+                        is_leaf=lambda x: x is None or is_rowsparse(x))
+                with jax.named_scope(TELEMETRY):
+                    # the single-device union count: distinct ids across
+                    # shards
+                    sub_rows = count_unique_ids(
+                        jax.lax.all_gather(used_ids, s_axis))
                 out = (agg, loss, sub_rows)
             else:
-                update = jax.tree.map(lambda g: jax.lax.pmean(g, s_axis),
-                                      update)
+                with jax.named_scope(AGGREGATE):
+                    update = jax.tree.map(lambda g: jax.lax.pmean(g, s_axis),
+                                          update)
                 out = (update, loss, jnp.zeros((), jnp.int32))
             if not telemetry:
                 return out
             # the flat path never compresses under sharding (topk/int8 are
             # rejected combos above), so pre == post: the L2 of the combined
             # replicated aggregate is the honest per-round figure here
-            sq = tree_sq_sum(out[0])
+            sq = _sq(out[0])
             tel = {"norm_pre_sq": sq, "norm_post_sq": sq}
             if sparse:
-                tel["used_ids"] = used_ids[None]
-                # used_ids is already the cross-shard union (gathered above);
-                # out_spec P(axis) reassembles one count per device
-                # repro-lint: ok shard-missing-psum -- deliberately per-shard count of the already-gathered union
-                tel["shard_union"] = (used_ids >= 0).sum(
-                    dtype=jnp.int32)[None]
+                with jax.named_scope(TELEMETRY):
+                    tel["used_ids"] = used_ids[None]
+                    # used_ids is already the cross-shard union (gathered
+                    # above); out_spec P(axis) reassembles one count per
+                    # device
+                    # repro-lint: ok shard-missing-psum -- deliberately per-shard count of the already-gathered union
+                    tel["shard_union"] = (used_ids >= 0).sum(
+                        dtype=jnp.int32)[None]
             return out + (tel,)
 
         def _shard_out_specs():
@@ -1208,26 +1242,28 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
                     agg = boxed_like(agg, params)
                 new_state = apply_dense(state, agg, counts)
             metrics = {"loss": loss}
-            if sparse and vocab:
-                denom = vocab if k_real is None else k_real * vocab
-                metrics["sub_rows"] = sub_rows
-                metrics["density"] = sub_rows / denom
-            if telemetry:
-                used = None
+            with jax.named_scope(TELEMETRY):
                 if sparse and vocab:
-                    u = tel["used_ids"]
-                    # stacked: pad clients sit at the END of the reassembled
-                    # (kp, R) stack (cyclic-repeat padding), so [:k_real]
-                    # recovers the real cohort. Flat: one per-shard id vector
-                    # per device — their union is the cohort union.
-                    used = (u[:k_real] if k_real is not None
-                            else union_ids_vec(u, vocab))
-                union, dropped, mass, per_client = _cohort_drop_tel(
-                    data, used)
-                metrics["telemetry"] = _assemble_tel(
-                    union, dropped, mass, per_client, agg_tree, counts,
-                    tel["norm_pre_sq"], tel["norm_post_sq"],
-                    shard_union_sizes=tel.get("shard_union"))
+                    denom = vocab if k_real is None else k_real * vocab
+                    metrics["sub_rows"] = sub_rows
+                    metrics["density"] = sub_rows / denom
+                if telemetry:
+                    used = None
+                    if sparse and vocab:
+                        u = tel["used_ids"]
+                        # stacked: pad clients sit at the END of the
+                        # reassembled (kp, R) stack (cyclic-repeat padding),
+                        # so [:k_real] recovers the real cohort. Flat: one
+                        # per-shard id vector per device — their union is
+                        # the cohort union.
+                        used = (u[:k_real] if k_real is not None
+                                else union_ids_vec(u, vocab))
+                    union, dropped, mass, per_client = _cohort_drop_tel(
+                        data, used)
+                    metrics["telemetry"] = _assemble_tel(
+                        union, dropped, mass, per_client, agg_tree, counts,
+                        tel["norm_pre_sq"], tel["norm_post_sq"],
+                        shard_union_sizes=tel.get("shard_union"))
             return new_state, metrics
 
         return sharded_step
@@ -1239,65 +1275,74 @@ def build_round_step(plan: RoundPlan, loss_fn: Callable, boxed_params_template,
         counts = batch_counts(heat)
         update, fwd_loss, used_ids, data = run_local(params, data, sub_ids)
         _debug_check_ids(used_ids, data)
-        pre_sq = tree_sq_sum(update) if telemetry else None
+        pre_sq = _sq(update)
 
         agg_tree = None
         if sparse:
-            if transport.topk or transport.int8:
-                key = (jax.random.fold_in(base_key, state.rounds)
-                       if transport.int8 else None)
-                update = compress_delta_tree(update, topk=transport.topk,
-                                             int8=transport.int8, key=key)
-            post_sq = tree_sq_sum(update) if telemetry else None
-            if local.stacked:
-                k = data[feature_keys[0]].shape[0]
-                agg = sparse_cohort_aggregate(
-                    update, heat_spec, counts, n_total, k,
-                    correct=server.correct,
-                    union_backend=transport.union_backend)
-            else:
-                def fix(leaf, space):
-                    if is_rowsparse(leaf):
-                        h = (counts.get(space[0])
-                             if server.correct and space is not None else None)
-                        return correct_rowsparse(leaf, h, n_total)
-                    if server.correct:
-                        return correct_dense_leaf(leaf, space, counts, n_total)
-                    return leaf
+            with jax.named_scope(AGGREGATE):
+                if transport.topk or transport.int8:
+                    key = (jax.random.fold_in(base_key, state.rounds)
+                           if transport.int8 else None)
+                    update = compress_delta_tree(update, topk=transport.topk,
+                                                 int8=transport.int8, key=key)
+                if local.stacked:
+                    k = data[feature_keys[0]].shape[0]
+                    agg = sparse_cohort_aggregate(
+                        update, heat_spec, counts, n_total, k,
+                        correct=server.correct,
+                        union_backend=transport.union_backend)
+                else:
+                    def fix(leaf, space):
+                        if is_rowsparse(leaf):
+                            h = (counts.get(space[0])
+                                 if server.correct and space is not None
+                                 else None)
+                            return correct_rowsparse(leaf, h, n_total)
+                        if server.correct:
+                            return correct_dense_leaf(leaf, space, counts,
+                                                      n_total)
+                        return leaf
 
-                agg = jax.tree.map(
-                    fix, update, heat_spec.leaf_spaces,
-                    is_leaf=lambda x: x is None or is_rowsparse(x))
+                    agg = jax.tree.map(
+                        fix, update, heat_spec.leaf_spaces,
+                        is_leaf=lambda x: x is None or is_rowsparse(x))
+            post_sq = _sq(update)
             agg_tree = agg
             new_state = apply_sparse(state, agg)
         else:
             post_sq = pre_sq          # dense transport: no wire compression
-            if isinstance(local, SubmodelReplicatedLocal):
-                # submodel replicas against a dense server transport: the
-                # born-sparse per-client deltas scatter back to dense stacks
-                update = _densify_stacked(update)
-            if local.stacked:
-                update = jax.tree.map(lambda d: d.mean(axis=0), update)
+            with jax.named_scope(AGGREGATE):
                 if isinstance(local, SubmodelReplicatedLocal):
-                    update = boxed_like(update, params)
+                    # submodel replicas against a dense server transport:
+                    # the born-sparse per-client deltas scatter back to
+                    # dense stacks
+                    update = _densify_stacked(update)
+                if local.stacked:
+                    update = jax.tree.map(lambda d: d.mean(axis=0), update)
+                    if isinstance(local, SubmodelReplicatedLocal):
+                        update = boxed_like(update, params)
             new_state = apply_dense(state, update, counts)
 
-        if local.stacked:
-            first = jax.tree.map(lambda x: x[:, 0], data)
-            loss = jax.vmap(lambda b: loss_fn(params, b))(first).mean()
-        else:
-            loss = fwd_loss
+        with jax.named_scope(LOSS):
+            if local.stacked:
+                first = jax.tree.map(lambda x: x[:, 0], data)
+                loss = jax.vmap(lambda b: loss_fn(params, b))(first).mean()
+            else:
+                loss = fwd_loss
         metrics = {"loss": loss}
-        if sparse and used_ids is not None and vocab:
-            sub_rows = (used_ids >= 0).sum()
-            denom = vocab if used_ids.ndim == 1 else used_ids.shape[0] * vocab
-            metrics["sub_rows"] = sub_rows
-            metrics["density"] = sub_rows / denom
-        if telemetry:
-            union, dropped, mass, per_client = _cohort_drop_tel(data, used_ids)
-            metrics["telemetry"] = _assemble_tel(
-                union, dropped, mass, per_client, agg_tree, counts,
-                pre_sq, post_sq)
+        with jax.named_scope(TELEMETRY):
+            if sparse and used_ids is not None and vocab:
+                sub_rows = (used_ids >= 0).sum()
+                denom = (vocab if used_ids.ndim == 1
+                         else used_ids.shape[0] * vocab)
+                metrics["sub_rows"] = sub_rows
+                metrics["density"] = sub_rows / denom
+            if telemetry:
+                union, dropped, mass, per_client = _cohort_drop_tel(
+                    data, used_ids)
+                metrics["telemetry"] = _assemble_tel(
+                    union, dropped, mass, per_client, agg_tree, counts,
+                    pre_sq, post_sq)
         return new_state, metrics
 
     return step

@@ -15,7 +15,6 @@ one dispatch system, two entry points.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import time
@@ -45,9 +44,11 @@ from repro.sharding.logical import unbox
 from repro.sparse.comm import CommStats, model_comm_meta
 from repro.sparse.encode import tree_leaf_at
 from repro.sparse.rowsparse import count_unique_ids, unique_ids_padded
-from repro.telemetry import PhaseTimer, TraceSink
+from repro.telemetry import TraceSink
 from repro.telemetry.round import (RoundTelemetry, split_rounds,
                                    telemetry_to_host)
+from repro.telemetry.spans import (ACCOUNT, CALL, CHUNK, DISPATCH, SAMPLE,
+                                   SUB_IDS, counters, host_pull, span)
 
 
 @dataclass
@@ -64,7 +65,10 @@ class RoundRecord:
                                      # excluded; blended mean only when every
                                      # dispatch of the stretch compiled)
     compile_time: float = 0.0        # seconds spent in compiling dispatches
-                                     # since the last record (0 once warm)
+                                     # since the last record (0 once warm);
+                                     # a dispatch compiles when JAX reports
+                                     # a backend compile or a persistent-
+                                     # cache load during it
 
 
 # ---------------------------------------------------------------------------
@@ -184,10 +188,8 @@ class FederatedTrainer:
         self._is_sparse = False
         self.telemetry_enabled = bool(telemetry)
         self.sink = sink if sink is not None else TraceSink()
-        self.timer = PhaseTimer()
         self.telemetry_log: List[Dict[str, Any]] = []
-        self._compiled_keys: set = set()      # jit-cache keys seen -> warm
-        self._last_dispatch_compiled = False
+        self._record_counts = counters()      # at the last record event
         # buffered-async engines, keyed by (server slot, telemetry flag);
         # the streaming-heat EMA persists across run_async calls
         self._async_engines: Dict[Any, Any] = {}
@@ -349,18 +351,6 @@ class FederatedTrainer:
         return HeatStats(counts=np.asarray(counts, np.float64), total=float(total),
                          name="vocab")
 
-    def _mark_dispatch(self, key) -> None:
-        """Record whether the NEXT jitted dispatch will compile.
-
-        ``key`` names the executable variant about to run — ``("step", cap)``,
-        ``("engine", n, cap)``, ``("dense",)``, ``("central",)`` — mirroring
-        the static arguments that actually key the jit cache, so ``run()``
-        can attribute wall time to compile vs steady state without poking
-        jit internals.
-        """
-        self._last_dispatch_compiled = key not in self._compiled_keys
-        self._compiled_keys.add(key)
-
     def _record_telemetry(self, tel, rnd: int,
                           comm: Optional[CommStats] = None) -> None:
         """Append one round's telemetry to ``telemetry_log`` and the sink.
@@ -390,13 +380,31 @@ class FederatedTrainer:
         per-round host-side work left on the sparse path.
         """
         cfg = self.cfg
-        ids = self.np_rng.choice(self.ds.num_clients, size=cfg.clients_per_round,
-                                 replace=False)
-        cohort = sample_cohort_batch(self.ds, ids, cfg.local_iters,
-                                     cfg.local_batch, self.np_rng)
-        feats = np.concatenate([np.asarray(cohort[k]).reshape(len(ids), -1)
-                                for k in self._feature_batch_keys], axis=1)
+        with span(SAMPLE):
+            ids = self.np_rng.choice(self.ds.num_clients,
+                                     size=cfg.clients_per_round,
+                                     replace=False)
+            cohort = sample_cohort_batch(self.ds, ids, cfg.local_iters,
+                                         cfg.local_batch, self.np_rng)
+            feats = np.concatenate(
+                [np.asarray(cohort[k]).reshape(len(ids), -1)
+                 for k in self._feature_batch_keys], axis=1)
         return cohort, feats
+
+    def _cohort_sub_ids(self, feats: np.ndarray):
+        """``(feats, valid_counts, capacity, sub_ids)`` of stacked feature
+        ids ``(K, M)``: the ids on the device, the per-client counts pulled
+        to the host (the one sync before the round can be dispatched), their
+        pow2 bucket, and the sub-ids derived on the device at that
+        capacity."""
+        with span(SUB_IDS):
+            feats = jnp.asarray(feats)
+            valid_counts = host_pull(
+                count_sub_ids(feats, self.ds.num_features), "count")
+            # pow2 capacity bounds jit recompiles to O(log V) variants
+            capacity = pow2_capacity(int(valid_counts.max()))
+            sub_ids = derive_sub_ids(feats, self.ds.num_features, capacity)
+        return feats, valid_counts, capacity, sub_ids
 
     def _log_sparse_comm(self, valid_counts: np.ndarray, capacity: int):
         """Comm accounting for one sparse round from per-client sub-id counts.
@@ -415,19 +423,16 @@ class FederatedTrainer:
 
     def _run_sparse_round(self) -> float:
         cohort, feats = self._sample_sparse_cohort()
-        feats = jnp.asarray(feats)
-        valid_counts = np.asarray(count_sub_ids(feats, self.ds.num_features))
-        # pow2 capacity bounds jit recompiles to O(log V) variants
-        capacity = pow2_capacity(int(valid_counts.max()))
-        sub_ids = derive_sub_ids(feats, self.ds.num_features, capacity)
-        cohort = {k: jnp.asarray(v) for k, v in cohort.items()}
-        self._mark_dispatch(("step", capacity))
-        self.state, metrics = self._sparse_step(self.state, cohort, sub_ids)
+        _, valid_counts, capacity, sub_ids = self._cohort_sub_ids(feats)
+        with span(DISPATCH):
+            cohort = {k: jnp.asarray(v) for k, v in cohort.items()}
+            self.state, metrics = self._sparse_step(self.state, cohort, sub_ids)
         self._last_capacity = capacity
-        self._log_sparse_comm(valid_counts, capacity)
-        self._record_telemetry(metrics.get("telemetry"), self._rounds_run,
-                               comm=self.comm_log[-1])
-        return float(metrics["loss"])
+        with span(ACCOUNT):
+            self._log_sparse_comm(valid_counts, capacity)
+            self._record_telemetry(metrics.get("telemetry"),
+                                   self._rounds_run, comm=self.comm_log[-1])
+        return float(host_pull(metrics["loss"], "loss"))
 
     def run_rounds(self, n: int) -> List[float]:
         """Drive ``n`` rounds through the in-jit engine (one ``lax.scan``).
@@ -452,32 +457,34 @@ class FederatedTrainer:
         if cfg.algorithm == "central" or not self._is_sparse:
             return [self.run_round() for _ in range(n)]
         k = cfg.clients_per_round
-        cohorts, feats = [], []
-        for _ in range(n):
-            c, f = self._sample_sparse_cohort()
-            cohorts.append(c)
-            feats.append(f)
-        stacked = {key: jnp.asarray(np.stack([c[key] for c in cohorts]))
-                   for key in cohorts[0]}
-        flat_feats = jnp.asarray(np.stack(feats)).reshape(n * k, -1)
-        valid_counts = np.asarray(
-            count_sub_ids(flat_feats, self.ds.num_features)).reshape(n, k)
-        capacity = pow2_capacity(int(valid_counts.max()))
-        sub_ids = derive_sub_ids(flat_feats, self.ds.num_features,
-                                 capacity).reshape(n, k, capacity)
-        self._mark_dispatch(("engine", n, capacity))
-        self.state, metrics = self._sparse_engine(self.state, stacked, sub_ids)
-        losses = np.asarray(metrics["loss"])
-        self._last_capacity = capacity
-        # telemetry rode the scan: each field gained a leading round axis
-        tel_events = (split_rounds(metrics["telemetry"], n)
-                      if "telemetry" in metrics else [None] * n)
-        for r in range(n):
-            self._rounds_run += 1
-            self._log_sparse_comm(valid_counts[r], capacity)
-            self._record_telemetry(tel_events[r], self._rounds_run,
-                                   comm=self.comm_log[-1])
-        return [float(l) for l in losses]
+        with span(CALL, driver="run_rounds", first_round=self._rounds_run + 1,
+                  rounds=n):
+            cohorts, feats = [], []
+            for _ in range(n):
+                c, f = self._sample_sparse_cohort()
+                cohorts.append(c)
+                feats.append(f)
+            _, valid_counts, capacity, sub_ids = self._cohort_sub_ids(
+                np.concatenate(feats, axis=0))
+            valid_counts = valid_counts.reshape(n, k)
+            with span(DISPATCH):
+                stacked = {key: jnp.asarray(np.stack([c[key] for c in cohorts]))
+                           for key in cohorts[0]}
+                sub_ids = sub_ids.reshape(n, k, capacity)
+                self.state, metrics = self._sparse_engine(self.state, stacked, sub_ids)
+            losses = host_pull(metrics["loss"], "loss")
+            self._last_capacity = capacity
+            with span(ACCOUNT):
+                # telemetry rode the scan: each field gained a leading round
+                # axis
+                tel_events = (split_rounds(metrics["telemetry"], n)
+                              if "telemetry" in metrics else [None] * n)
+                for r in range(n):
+                    self._rounds_run += 1
+                    self._log_sparse_comm(valid_counts[r], capacity)
+                    self._record_telemetry(tel_events[r], self._rounds_run,
+                                           comm=self.comm_log[-1])
+            return [float(l) for l in losses]
 
     def run_async(self, sim: ArrivalSim,
                   server: Optional[BufferedAsyncServerUpdate] = None
@@ -513,6 +520,14 @@ class FederatedTrainer:
         srv = (server if server is not None else BufferedAsyncServerUpdate(
             algorithm=self.plan.server.algorithm,
             buffer_size=cfg.clients_per_round))
+        sch = sim.compile(cfg.clients_per_round, srv.buffer_size)
+        with span(CALL, driver="run_async", first_round=self._rounds_run + 1,
+                  rounds=sch.num_fires):
+            return self._run_async(sim, srv, sch)
+
+    def _run_async(self, sim: ArrivalSim, srv: BufferedAsyncServerUpdate,
+                   sch) -> List[float]:
+        cfg = self.cfg
         key = (srv, self.telemetry_enabled)
         if key not in self._async_engines:
             plan = dataclasses.replace(self.plan, server=srv)
@@ -524,47 +539,42 @@ class FederatedTrainer:
                                                      donate_argnums=(0,)))
         eng, run = self._async_engines[key]
 
-        k = cfg.clients_per_round
-        sch = sim.compile(k, srv.buffer_size)
         cohorts, feats = [], []
         for _ in range(sim.num_rounds):
             c, f = self._sample_sparse_cohort()
             cohorts.append(c)
             feats.append(f)
-        tasks = {key_: jnp.asarray(np.concatenate(
-            [np.asarray(c[key_]) for c in cohorts], axis=0))
-            for key_ in cohorts[0]}
-        flat_feats = jnp.asarray(np.concatenate(feats, axis=0))
-        valid_counts = np.asarray(count_sub_ids(flat_feats,
-                                                self.ds.num_features))
-        capacity = pow2_capacity(int(valid_counts.max()))
-        sub_ids = derive_sub_ids(flat_feats, self.ds.num_features, capacity)
+        flat_feats, valid_counts, capacity, sub_ids = self._cohort_sub_ids(
+            np.concatenate(feats, axis=0))
 
-        state0 = eng.init(self.state, num_slots=sch.num_slots,
-                          capacity=capacity,
-                          heat_ema=(self._async_heat_ema
-                                    if srv.heat == "ema" else None))
-        self._mark_dispatch(("async", srv, sch.num_events, capacity,
-                             sch.num_slots))
-        state, ys = run(state0, sch.event_arrays(), tasks, sub_ids,
-                        flat_feats if self.telemetry_enabled else None)
+        with span(DISPATCH):
+            tasks = {key_: jnp.asarray(np.concatenate(
+                [np.asarray(c[key_]) for c in cohorts], axis=0))
+                for key_ in cohorts[0]}
+            state0 = eng.init(self.state, num_slots=sch.num_slots,
+                              capacity=capacity,
+                              heat_ema=(self._async_heat_ema
+                                        if srv.heat == "ema" else None))
+            state, ys = run(state0, sch.event_arrays(), tasks, sub_ids,
+                            flat_feats if self.telemetry_enabled else None)
         self.state = state.server
         if srv.heat == "ema":
             self._async_heat_ema = state.heat_ema
         self._last_capacity = capacity
 
         fired = np.flatnonzero(np.asarray(sch.fire))
-        losses = np.asarray(ys["loss"])[fired]
-        tel_events = (split_rounds(ys["telemetry"], sch.num_events)
-                      if "telemetry" in ys else None)
-        m = srv.buffer_size
-        for f in range(sch.num_fires):
-            self._rounds_run += 1
-            arrived = sch.arrival_tasks[f * m:(f + 1) * m]
-            self._log_sparse_comm(valid_counts[arrived], capacity)
-            self._record_telemetry(
-                tel_events[fired[f]] if tel_events else None,
-                self._rounds_run, comm=self.comm_log[-1])
+        losses = host_pull(ys["loss"], "loss")[fired]
+        with span(ACCOUNT):
+            tel_events = (split_rounds(ys["telemetry"], sch.num_events)
+                          if "telemetry" in ys else None)
+            m = srv.buffer_size
+            for f in range(sch.num_fires):
+                self._rounds_run += 1
+                arrived = sch.arrival_tasks[f * m:(f + 1) * m]
+                self._log_sparse_comm(valid_counts[arrived], capacity)
+                self._record_telemetry(
+                    tel_events[fired[f]] if tel_events else None,
+                    self._rounds_run, comm=self.comm_log[-1])
         return [float(l) for l in losses]
 
     def _make_central_step(self):
@@ -580,27 +590,40 @@ class FederatedTrainer:
 
     # ------------------------------------------------------------------
     def run_round(self) -> float:
+        with span(CALL, driver="run_round", first_round=self._rounds_run + 1,
+                  rounds=1):
+            self._rounds_run += 1
+            if self.cfg.algorithm == "central":
+                return self._run_central_round()
+            if self._is_sparse:
+                return self._run_sparse_round()
+            return self._run_dense_round()
+
+    def _run_central_round(self) -> float:
         cfg = self.cfg
-        self._rounds_run += 1
-        if cfg.algorithm == "central":
+        with span(SAMPLE):
             batches = pooled_batches(self.ds, cfg.local_iters,
                                      cfg.local_batch * cfg.clients_per_round,
                                      self.np_rng)
+        with span(DISPATCH):
             batches = {k: jnp.asarray(v) for k, v in batches.items()}
-            self._mark_dispatch(("central",))
             self.state, loss = self._central_step(self.state, batches)
-            return float(loss)
-        if self._is_sparse:
-            return self._run_sparse_round()
-        ids = self.np_rng.choice(self.ds.num_clients, size=cfg.clients_per_round,
-                                 replace=False)
-        cohort = sample_cohort_batch(self.ds, ids, cfg.local_iters, cfg.local_batch,
-                                     self.np_rng)
-        cohort = {k: jnp.asarray(v) for k, v in cohort.items()}
-        self._mark_dispatch(("dense",))
-        self.state, metrics = self._round_step(self.state, cohort)
-        self._record_telemetry(metrics.get("telemetry"), self._rounds_run)
-        return float(metrics["loss"])
+        return float(host_pull(loss, "loss"))
+
+    def _run_dense_round(self) -> float:
+        cfg = self.cfg
+        with span(SAMPLE):
+            ids = self.np_rng.choice(self.ds.num_clients,
+                                     size=cfg.clients_per_round,
+                                     replace=False)
+            cohort = sample_cohort_batch(self.ds, ids, cfg.local_iters,
+                                         cfg.local_batch, self.np_rng)
+        with span(DISPATCH):
+            cohort = {k: jnp.asarray(v) for k, v in cohort.items()}
+            self.state, metrics = self._round_step(self.state, cohort)
+        with span(ACCOUNT):
+            self._record_telemetry(metrics.get("telemetry"), self._rounds_run)
+        return float(host_pull(metrics["loss"], "loss"))
 
     def evaluate(self) -> float:
         if self.predict_fn is None:
@@ -643,13 +666,25 @@ class FederatedTrainer:
         excluded — falling back to the blended mean only when EVERY dispatch
         of the stretch compiled, so it is never zero), and the compile cost
         lands in ``RoundRecord.compile_time`` (zero once the jit caches are
-        warm). The same samples feed ``self.timer`` (phases ``"round"``,
-        ``"eval"``, ``"train_loss"``).
+        warm). A dispatch compiles when JAX's monitoring events report a
+        backend compile or a persistent-cache load during it. Each
+        ``record`` event of the sink also carries ``host_syncs`` and
+        ``compiles``: the blocking pulls and the XLA compiles since the
+        previous record.
 
         ``profile_dir``: wrap the whole call in a ``jax.profiler`` trace
-        written under that directory (TensorBoard-loadable), with one
-        ``TraceAnnotation`` per dispatched stretch so kernels are
-        attributable to training phases.
+        written under that directory. The trace holds the trainer's spans
+        on the profiler's clock (``repro.telemetry.spans``): one
+        ``fedsub.chunk`` per stretch (args ``first_round``, ``rounds``),
+        inside it one ``fedsub.call`` per driver call, and inside that
+        ``fedsub.sample`` per cohort, ``fedsub.sub_ids``,
+        ``fedsub.dispatch``, ``fedsub.account`` and one ``fedsub.sync`` per
+        blocking pull (arg ``what``). The device operations carry the round
+        step's scopes (``fedsub.local``, ``fedsub.aggregate``,
+        ``fedsub.apply``, ``fedsub.loss``, ``fedsub.telemetry``) in their
+        HLO metadata. Open the directory in TensorBoard's profile plugin, or
+        read the ``.xplane.pb`` under it with
+        ``jax.profiler.ProfileData.from_file``.
 
         ``RoundRecord.round`` numbers continue from the trainer's global
         round counter, so repeated ``run()`` calls (or mixing ``run_round``
@@ -658,57 +693,49 @@ class FederatedTrainer:
         if profile_dir is not None:
             jax.profiler.start_trace(str(profile_dir))
         try:
-            return self._run_chunks(rounds, eval_every, verbose, engine,
-                                    annotate=profile_dir is not None)
+            return self._run_chunks(rounds, eval_every, verbose, engine)
         finally:
             if profile_dir is not None:
                 jax.profiler.stop_trace()
 
     def _run_chunks(self, rounds: int, eval_every: int, verbose: bool,
-                    engine: bool, annotate: bool = False):
+                    engine: bool):
         done = 0
         # the engine only exists on the sparse path; dense/central configs
         # fall back to per-round dispatches (where compile attribution is
         # per round, not per chunk)
         use_engine = (engine and self._is_sparse
                       and self.cfg.algorithm != "central")
+
+        def executables() -> int:
+            c = counters()
+            return c["compiles"] + c["cache_loads"]
+
         while done < rounds:
             chunk = min(eval_every - done % eval_every, rounds - done)
-            ctx = (jax.profiler.TraceAnnotation(
-                f"rounds[{self._rounds_run}:{self._rounds_run + chunk}]")
-                if annotate else contextlib.nullcontext())
             compile_s = 0.0
             steady: List[float] = []
-
-            def account(dt: float, per_round: float):
-                nonlocal compile_s
-                if self._last_dispatch_compiled:
-                    compile_s += dt
-                    self.timer.add("round", dt, compile=True)
-                else:
-                    steady.append(per_round)
-                    self.timer.add("round", per_round)
-
+            calls, per_call = (1, chunk) if use_engine else (chunk, 1)
             t0 = time.perf_counter()
-            with ctx:
-                if use_engine:
-                    self.run_rounds(chunk)
-                    dt = time.perf_counter() - t0
-                    account(dt, dt / chunk)
-                else:
-                    for _ in range(chunk):
-                        t1 = time.perf_counter()
+            with span(CHUNK, first_round=self._rounds_run + 1, rounds=chunk):
+                for _ in range(calls):
+                    made = executables()
+                    t1 = time.perf_counter()
+                    if use_engine:
+                        self.run_rounds(chunk)
+                    else:
                         self.run_round()
-                        dt = time.perf_counter() - t1
-                        account(dt, dt)
+                    dt = time.perf_counter() - t1
+                    if executables() != made:
+                        compile_s += dt
+                    else:
+                        steady.append(dt / per_call)
             total = time.perf_counter() - t0
             wall = sum(steady) / len(steady) if steady else total / chunk
             done += chunk
             if done % eval_every == 0 or done == rounds:
-                with self.timer.phase("eval"):
-                    metric = self.evaluate()
-                with self.timer.phase("train_loss"):
-                    tl = self.train_loss()
+                metric = self.evaluate()
+                tl = self.train_loss()
                 rec = RoundRecord(self._rounds_run, tl, metric,
                                   wall_time=wall, compile_time=compile_s)
                 if self.comm_log:
@@ -717,8 +744,12 @@ class FederatedTrainer:
                     rec.bytes_down = s["bytes_down_sparse"]
                     rec.density = s["mean_density"]
                 self.history.append(rec)
-                self.sink.emit({"event": "record",
-                                **dataclasses.asdict(rec)})
+                now, last = counters(), self._record_counts
+                self._record_counts = now
+                self.sink.emit({
+                    "event": "record", **dataclasses.asdict(rec),
+                    "host_syncs": now["host_syncs"] - last["host_syncs"],
+                    "compiles": now["compiles"] - last["compiles"]})
                 if verbose:
                     self.sink.report(
                         f"[{self.cfg.algorithm}] round {self._rounds_run}: "

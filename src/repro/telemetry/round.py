@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.sparse.rowsparse import (count_unique_ids, is_rowsparse,
                                     membership, unique_ids_padded)
+from repro.telemetry.spans import host_pull
 
 Array = jax.Array
 
@@ -195,20 +196,22 @@ def telemetry_to_host(tel: RoundTelemetry) -> dict:
 
     Works on a stacked telemetry too (each field gains a leading round axis
     under the scan engine) — use :func:`split_rounds` to slice it per round.
+    Each field is one blocking pull (:func:`~repro.telemetry.spans.host_pull`).
     """
     out = {}
     for name, v in tel._asdict().items():
         if v is None:
             out[name] = None
             continue
-        a = np.asarray(jax.device_get(v))
+        a = host_pull(v, f"telemetry.{name}")
         out[name] = a.item() if a.ndim == 0 else a.tolist()
     return out
 
 
 def split_rounds(tel: RoundTelemetry, n: int) -> list:
-    """Split a scan-stacked telemetry (leading axis ``n``) into host dicts."""
-    host = {name: (None if v is None else np.asarray(jax.device_get(v)))
+    """Split a scan-stacked telemetry (leading axis ``n``) into host dicts,
+    one blocking pull per field."""
+    host = {name: (None if v is None else host_pull(v, f"telemetry.{name}"))
             for name, v in tel._asdict().items()}
     events = []
     for r in range(n):

@@ -66,6 +66,31 @@ def test_compiler_params_without_semantics_fires():
         violations[0].message
 
 
+def test_donated_reuse_sees_statements_inside_with_blocks():
+    """A donating call that rebinds its holder inside a ``with`` block is
+    clean; a re-read after it, inside or after the block, still fires."""
+    src = textwrap.dedent("""
+        import contextlib
+        import jax
+
+        step = jax.jit(lambda s, b: s + b, donate_argnums=(0,))
+
+
+        def safe(state, batch):
+            with contextlib.nullcontext():
+                state = step(state, batch)
+            return state
+
+
+        def bad(state, batch):
+            with contextlib.nullcontext():
+                new = step(state, batch)
+            return new + state
+    """)
+    violations, _ = lint.lint_source(src, "mod.py")
+    assert [(v.rule, v.line) for v in violations] == [("donated-reuse", 17)]
+
+
 def test_cli_nonzero_on_fixtures_zero_on_clean(tmp_path):
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
     bad = subprocess.run(
