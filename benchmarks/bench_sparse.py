@@ -7,7 +7,7 @@ Three sections, all emitted to the CSV stream and to
    deltas over a (V, D) feature table, cohort-mean + FedSubAvg correction on
    both planes.
 2. union-backend comparison for ``aggregate_rowsparse``: jnp-sort vs
-   jnp-bitmap vs the fused ``union_segsum`` Pallas kernel across
+   jnp-bitmap vs the ``union_segsum`` Pallas kernel across
    V in {65k, 262k} x density in {1%, 10%}. On CPU the kernel runs in
    interpret mode, which executes the kernel body in Python — honest but
    orders of magnitude off the compiled path — so off-TPU the pallas column

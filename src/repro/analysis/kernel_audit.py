@@ -31,8 +31,9 @@ Three contracts per kernel:
 - :func:`cost_model` — analytic bytes-touched and FLOPs per kernel
   invocation from the grid x BlockSpec structure. Operand fetch counts come
   from the grid dims each index map depends on, so an operand re-streamed
-  across an independent grid dim (e.g. ``union_segsum`` re-fetching the
-  ids/rows stream once per vocab block) shows up as ``restream > 1``. The
+  across an independent grid dim (e.g. a union kernel blocked by vocabulary
+  re-fetching its ids/rows once per vocab block) shows up as
+  ``restream > 1``. The
   numbers feed ``bench_sparse``'s kernel roofline section (achieved vs
   analytic bandwidth per union backend), gated by ``check_regression.py``.
 

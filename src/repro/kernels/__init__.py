@@ -2,7 +2,7 @@
 
 heat_scatter      -- FedSubAvg's fused aggregate+correct embedding update
 rowsparse_scatter -- generalisation to cohort row-sparse deltas (sparse plane)
-union_segsum      -- fused union build + segment-sum + heat scaling producing
+union_segsum      -- sorted-run union + segment-sum + heat scaling producing
                      the union-id RowSparse aggregate (sparse server engine)
 flash_attention   -- causal GQA flash attention (+ sliding window)
 flash_decode      -- single-token decode against long KV caches

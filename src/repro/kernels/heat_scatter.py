@@ -143,8 +143,9 @@ def _tpu_compiler_params(semantics=("parallel", "arbitrary")):
     ``semantics`` declares one entry per grid dim. ``heat_scatter``'s vocab
     axis is safe to split across cores ('parallel': its vocab blocks touch
     disjoint output rows); a kernel that carries state across a grid dim
-    (e.g. ``union_segsum``'s SMEM union offset) must declare that dim
-    'arbitrary' or Megacore partitioning will corrupt it.
+    (e.g. ``union_segsum``'s resident output, added into across row tiles)
+    must declare that dim 'arbitrary' or Megacore partitioning will corrupt
+    it.
     """
     return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
 

@@ -63,8 +63,8 @@ class KernelEntry:
 
 
 # -- union_segsum -----------------------------------------------------------
-# 16 clients x 656 ids over a 64k vocab, D=64, union capacity 8192: both
-# grid dims >1 (nv=64, nt=11) and the row count is deliberately NOT a
+# 16 clients x 656 ids over a 64k vocab, D=64, union capacity 8192: the grid
+# (nt=11) has more than one tile and the row count is deliberately NOT a
 # multiple of t_blk so the wrapper's padding path is part of the trace.
 _US = dict(V=65536, K=16, R=656, D=64, CAP=8192)
 
@@ -76,7 +76,7 @@ def _build_union_segsum():
             jax.ShapeDtypeStruct((c["V"],), jnp.float32))
 
     def fn(ids, rows, heat):
-        return _us.union_segsum(ids, rows, heat, 64.0, c["CAP"], c["V"],
+        return _us.union_segsum(ids, rows, heat, 64.0, c["CAP"],
                                 interpret=False)
     return fn, args
 
@@ -84,17 +84,14 @@ def _build_union_segsum():
 def _guard_union_segsum() -> GuardReport:
     c = _US
     t = c["K"] * c["R"]
-    v_blk, t_blk = _us._block_sizes(c["V"], t, _us.DEFAULT_V_BLK,
-                                    _us.DEFAULT_T_BLK)
-    cap_p = c["CAP"] + v_blk
+    t_blk = _us._block_sizes(t, _us.DEFAULT_T_BLK)
     return GuardReport(
-        fits=_us.fits_vmem(c["CAP"], c["D"], num_rows=c["V"], t=t),
-        footprint=_us.vmem_footprint(c["CAP"], c["D"], num_rows=c["V"], t=t),
-        blocks={"ids": (1, (t_blk,)),
+        fits=_us.fits_vmem(c["CAP"], c["D"], t=t),
+        footprint=_us.vmem_footprint(c["CAP"], c["D"], t=t),
+        blocks={"off": (0, (-(-t // t_blk),)),
+                "slot": (1, (t_blk,)),
                 "rows": (2, (t_blk, c["D"])),
-                "heat": (3, (v_blk,)),
-                "out_ids": (4, (cap_p, 1)),
-                "out_rows": (5, (cap_p, c["D"]))},
+                "out_rows": (3, (c["CAP"] + t_blk, c["D"]))},
     )
 
 

@@ -40,17 +40,17 @@ def rowsparse_scatter(ids, rows, heat, total, vocab: int,
                               v_blk=v_blk, t_blk=t_blk, interpret=not _on_tpu())
 
 
-@functools.partial(jax.jit, static_argnames=("cap", "num_rows", "v_blk", "t_blk"))
-def union_segsum(ids, rows, heat, total, cap: int, num_rows: int,
-                 scale=1.0, v_blk: int = TILE_1D, t_blk: int = TILE_1D):
-    """Fused union + segment-sum + heat scaling (see kernel module).
+@functools.partial(jax.jit, static_argnames=("cap", "t_blk"))
+def union_segsum(ids, rows, heat, total, cap: int, scale=1.0,
+                 t_blk: int = TILE_1D):
+    """Union + segment-sum + heat scaling (see kernel module).
 
     ``total`` and ``scale`` are traced scalar operands — varying them (e.g.
     across rounds or in a sweep) hits the same compiled kernel; only the
-    true shape parameters (``cap``, ``num_rows``, blocks) are static.
+    true shape parameters (``cap``, the row tile) are static.
     """
-    return _union_segsum(ids, rows, heat, total, cap, num_rows, scale=scale,
-                         v_blk=v_blk, t_blk=t_blk, interpret=not _on_tpu())
+    return _union_segsum(ids, rows, heat, total, cap, scale=scale,
+                         t_blk=t_blk, interpret=not _on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "blk_q", "blk_k"))

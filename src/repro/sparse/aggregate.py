@@ -10,10 +10,11 @@ Three union backends, selected at runtime (``union_backend="auto"``):
 ``bitmap``  mark touched rows in a (V,) bitmap, rank by cumsum — O(V)
             streamed vector work, the CPU fast path for moderate V.
 ``sort``    sort/searchsorted — O(T log T), for huge feature spaces.
-``pallas``  the fused ``union_segsum`` kernel (``repro.kernels``): union
-            build, segment-sum and heat scaling in one blocked TPU program —
-            the server hot-loop path whenever the union fits VMEM (compiled
-            on TPU; interpret-mode parity elsewhere).
+``pallas``  the ``union_segsum`` kernel (``repro.kernels``): a sort of the
+            T ids, then the segment-sum as one-hot MXU matmuls over row
+            tiles — O(T log T + T t_blk D), no sweep of V; the server
+            hot-loop path whenever the union fits VMEM (compiled on TPU;
+            interpret-mode parity elsewhere).
 
 ``aggregate_rowsparse_dense`` additionally routes through the dense-output
 ``rowsparse_scatter`` kernel when the server applies into a dense table.
@@ -81,20 +82,20 @@ def _resolve_backend(backend: str, num_rows: int, cap: int,
                      row_elems: int, num_elems: int) -> str:
     """Runtime union-backend selection for ``"auto"``.
 
-    On TPU the fused ``union_segsum`` kernel wins whenever its VMEM-resident
+    On TPU the ``union_segsum`` kernel wins whenever its VMEM-resident
     union fits the budget; otherwise (and everywhere on CPU, where the
     interpreter would crawl) the jnp backends split by feature-space size.
-    ``num_rows``/``num_elems`` are forwarded so the budget check uses the
-    same block sizes the kernel will actually pick.
+    ``num_elems`` is forwarded so the budget check uses the same row tile
+    the kernel will actually pick.
     """
     if backend != "auto":
         return backend
     from repro.kernels.heat_scatter import on_tpu
     from repro.kernels.union_segsum import fits_vmem
-    # the kernel's grid scales with V/v_blk, so beyond the bitmap regime the
-    # sort backend wins regardless of how small the union is
+    # beyond the bitmap regime the sort backend is kept until a cell with
+    # such a table has measured the kernel there
     if (on_tpu() and num_rows <= _BITMAP_MAX_ROWS
-            and fits_vmem(cap, row_elems, num_rows=num_rows, t=num_elems)):
+            and fits_vmem(cap, row_elems, t=num_elems)):
         return "pallas"
     return "bitmap" if num_rows <= _BITMAP_MAX_ROWS else "sort"
 
@@ -105,8 +106,8 @@ def _union_and_slots(flat_ids: Array, num_rows: int, cap: int, backend: str):
     ``bitmap``: mark touched rows in a (V,) bitmap, rank by cumsum, compact
     with size-bounded ``nonzero`` — no sort, everything streams. ``sort``:
     the generic O(T log T) path for huge feature spaces. (The ``pallas``
-    backend never materialises slots — ``aggregate_rowsparse`` dispatches to
-    the fused ``union_segsum`` kernel before reaching here.)
+    backend derives its slots inside ``union_segsum`` —
+    ``aggregate_rowsparse`` dispatches to it before reaching here.)
     """
     if backend == "auto":
         backend = "bitmap" if num_rows <= _BITMAP_MAX_ROWS else "sort"
@@ -151,8 +152,7 @@ def aggregate_rowsparse(stacked: RowSparse, heat: Optional[Array] = None,
         # total/scale pass through untouched — the kernel takes them as
         # traced scalar operands, so they may be tracers (no recompile)
         union, summed = ops.union_segsum(
-            flat_ids, flat_rows, heat, total, cap, stacked.num_rows,
-            scale=scale)
+            flat_ids, flat_rows, heat, total, cap, scale=scale)
         return RowSparse(union, summed, stacked.num_rows)
 
     union, pos = _union_and_slots(flat_ids, stacked.num_rows, cap, union_backend)

@@ -6,8 +6,8 @@ direction: planted contract breakers — a carried-accumulator grid dim
 declared ``"parallel"`` and an over-budget BlockSpec — must fail with their
 named diagnostics, and a planted guard that under-reports its footprint or
 mispredicts its block picks must be caught as drift. The cost model is
-pinned on the one number the whole plane exists to expose: ``union_segsum``
-re-streams the ids/rows once per vocab block (restream = nv).
+pinned on the number the plane exists to expose: ``union_segsum``'s grid
+follows the rows, so it streams its slots and rows once (restream = 1).
 """
 import dataclasses
 import json
@@ -53,24 +53,25 @@ def test_registry_covers_every_pallas_call_site():
 
 def test_carried_dims_match_declared_semantics(reports):
     """The race detector recovers each kernel's true carried dims."""
-    assert reports["union_segsum"].race.required == [0, 1]
+    assert reports["union_segsum"].race.required == [0]
     assert reports["rowsparse_scatter"].race.required == [1]
     assert reports["flash_attention"].race.required == [2]
     assert reports["flash_decode"].race.required == [1]
 
 
 def test_union_segsum_restream_priced(reports):
-    """ids/rows are re-fetched once per vocab block: restream = nv."""
+    """The grid is (nt,) over row tiles: every operand, the slots/rows
+    stream among them, is fetched once (restream = 1)."""
     rep = reports["union_segsum"]
-    nv = rep.grid[0]
-    assert nv > 1
+    assert len(rep.grid) == 1 and rep.grid[0] > 1
     per_op = rep.cost.per_operand
-    assert max(op["restream"] for op in per_op.values()) == float(nv)
-    # the payload stream (ids: (T,) i32 and rows: (T, D) f32) is what
-    # restreams, not the vocab-partitioned heat
-    restreamed = [op for op in per_op.values()
-                  if op["kind"] == "input" and op["restream"] == float(nv)]
-    assert len(restreamed) >= 2
+    assert max(op["restream"] for op in per_op.values()) == 1.0
+    # the payload stream (slots: (T,) i32 and rows: (T, D) f32) is split
+    # over the grid's tiles, and each tile is fetched once
+    streamed = [op for op in per_op.values()
+                if op["kind"] == "input" and op["fetches"] == rep.grid[0]]
+    assert len(streamed) == 2
+    assert all(op["restream"] == 1.0 for op in streamed)
     assert rep.cost.bytes_touched > 0 and rep.cost.flops > 0
     assert rep.cost.hbm_seconds > 0 and rep.cost.compute_seconds > 0
 
@@ -217,8 +218,8 @@ def test_planted_guard_drift_is_caught():
 
     # block-pick drift: guard predicts a block shape the kernel never picks
     blocks = dict(honest.blocks)
-    idx, shape = blocks["ids"]
-    blocks["ids"] = (idx, (shape[0] * 2,))
+    idx, shape = blocks["slot"]
+    blocks["slot"] = (idx, (shape[0] * 2,))
     mispredict = dataclasses.replace(
         entry, guard=lambda: dataclasses.replace(honest, blocks=blocks))
     rep = ka.audit_kernel(mispredict)

@@ -189,40 +189,78 @@ def test_kernel_matches_sparse_aggregate(rng):
 # ---------------------------------------------------------------------------
 
 
+def _union_case(rng, case, v):
+    """One cohort for the union kernel: ``(stacked, heat or None, cap)``.
+
+    ``straddle`` draws 8 clients from 16 hot ids, so that sorted runs
+    cross row-tile boundaries; ``over_cap`` sets the capacity 3 below the union size;
+    ``all_pad`` holds all-pad clients over ``T = 65`` rows, not a multiple
+    of any tile; ``big_ids`` draws ids above ``2^24``, where float32 is no
+    longer exact; ``heat_none`` aggregates without the heat correction.
+    """
+    k, r, d = {"all_pad": (5, 13, 5), "straddle": (8, 12, 5)}.get(
+        case, (4, 12, 5))
+    base = (1 << 24) if case == "big_ids" else 0
+    pool = np.arange(base, base + 16 if case == "straddle" else v)
+    ids = np.full((k, r), -1, np.int32)
+    for i in range(k):
+        n = int(rng.integers(1, r + 1))
+        ids[i, :n] = np.sort(rng.choice(pool, size=n, replace=False))
+    if case == "all_pad":
+        ids[[1, 3]] = -1
+    rows = rng.normal(size=(k, r, d)).astype(np.float32)
+    rows[ids < 0] = 0
+    heat = np.zeros(v, np.float32)
+    np.add.at(heat, ids[ids >= 0], 1.0)
+    union_size = len(np.unique(ids[ids >= 0]))
+    cap = union_size - 3 if case == "over_cap" else None
+    stacked = RowSparse(jnp.asarray(ids), jnp.asarray(rows), v)
+    return stacked, (None if case == "heat_none" else jnp.asarray(heat)), cap
+
+
+def _straddles(ids, t_blk):
+    """Whether some id's sorted run crosses a multiple of ``t_blk``."""
+    flat = np.sort(ids[ids >= 0])
+    starts = np.flatnonzero(np.r_[True, flat[1:] != flat[:-1]])
+    ends = np.r_[starts[1:], len(flat)] - 1
+    return bool(np.any(starts // t_blk != ends // t_blk))
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2])
-@pytest.mark.parametrize("v,v_blk,t_blk", [
-    (64, 16, 32),
-    (101, 32, 64),       # V not a multiple of the block
-    (37, 8, 16),
+@pytest.mark.parametrize("v,t_blk,case", [
+    (64, 16, "straddle"),
+    (101, 32, "over_cap"),
+    (37, 16, "all_pad"),
+    ((1 << 24) + 48, 16, "big_ids"),
+    (64, 8, "heat_none"),
 ])
-def test_union_segsum_matches_jnp_backends(seed, v, v_blk, t_blk):
-    """Randomized cohorts (duplicate ids across clients by construction):
-    the fused kernel's RowSparse output equals both jnp backends'."""
+def test_union_segsum_matches_jnp_backends(seed, v, t_blk, case):
+    """The kernel's RowSparse output equals both jnp backends', at the
+    default row tile and at a small one that splits the cohort."""
     from repro.kernels.union_segsum import union_segsum
     rng = np.random.default_rng(seed)
-    k, d = 4, 5
-    ids_np, dense = _random_cohort(rng, k, v, d, max_rows=max(v // 3, 4))
-    heat = np.zeros(v, np.float64)
-    for i in range(k):
-        heat[ids_np[i][ids_np[i] >= 0]] += 1
-    stacked = jax.vmap(RowSparse.from_dense)(jnp.asarray(dense),
-                                             jnp.asarray(ids_np))
+    stacked, heat, cap = _union_case(rng, case, v)
+    ids_np = np.asarray(stacked.ids)
+    if case == "straddle":
+        assert _straddles(ids_np, t_blk)
+    if case == "big_ids":
+        assert ids_np.max() > (1 << 24) + 1
     total, scale = 24.0, 0.25
-    heat_j = jnp.asarray(heat, jnp.float32)
-    want = {b: aggregate_rowsparse(stacked, heat_j, total, scale,
-                                   union_backend=b)
+    want = {b: aggregate_rowsparse(stacked, heat, total, scale,
+                                   union_capacity=cap, union_backend=b)
             for b in ("bitmap", "sort")}
-    got = aggregate_rowsparse(stacked, heat_j, total, scale,
-                              union_backend="pallas")
+    got = aggregate_rowsparse(stacked, heat, total, scale,
+                              union_capacity=cap, union_backend="pallas")
+    if case == "over_cap":
+        assert int((got.ids >= 0).sum()) == got.capacity
     for b, w in want.items():
-        np.testing.assert_array_equal(np.asarray(got.ids), np.asarray(w.ids))
-        np.testing.assert_allclose(np.asarray(got.to_dense()),
-                                   np.asarray(w.to_dense()),
+        np.testing.assert_array_equal(np.asarray(got.ids), np.asarray(w.ids),
+                                      err_msg=b)
+        np.testing.assert_allclose(np.asarray(got.rows), np.asarray(w.rows),
                                    rtol=1e-5, atol=1e-5, err_msg=b)
-    # direct kernel call with explicit small blocks agrees too
-    u_ids, u_rows = union_segsum(stacked.ids, stacked.rows, heat_j, total,
-                                 got.capacity, v, scale=scale,
-                                 v_blk=v_blk, t_blk=t_blk)
+    # direct kernel call with an explicit small row tile agrees too
+    u_ids, u_rows = union_segsum(stacked.ids, stacked.rows, heat, total,
+                                 got.capacity, scale=scale, t_blk=t_blk)
     np.testing.assert_array_equal(np.asarray(u_ids), np.asarray(got.ids))
     np.testing.assert_allclose(np.asarray(u_rows), np.asarray(got.rows),
                                rtol=1e-5, atol=1e-5)
@@ -280,42 +318,45 @@ def test_union_backend_auto_selection(monkeypatch):
 
 
 def test_fits_vmem_uses_actual_block_sizes():
-    """Regression: the budget guard prices the blocks the kernel runs with.
-    A block is clamped to its array rounded up to the 1-D tile (a 4096
-    block over 64 rows runs as 1024), and never shrunk below the tile —
-    a small cohort or feature space is padded up to the block instead, so
-    the block keeps matching XLA's 1-D tiling on TPU."""
+    """Regression: the budget guard prices the row tile the kernel runs
+    with. A tile is clamped to the rows rounded up to the 1-D tile (a 4096
+    tile over 64 rows runs as 1024), and never shrunk below the tile — a
+    small cohort is padded up to the tile instead, so the tile keeps
+    matching XLA's 1-D tiling on TPU. No term depends on the vocabulary."""
     from repro.kernels.union_segsum import (TILE_1D, _block_sizes, fits_vmem,
                                             union_segsum, vmem_footprint)
     cap, d = 1024, 64
-    assert _block_sizes(64, 64, 4096, 4096) == (TILE_1D, TILE_1D)
-    assert not fits_vmem(cap, d, v_blk=4096, t_blk=4096)
-    assert fits_vmem(cap, d, num_rows=64, t=64, v_blk=4096, t_blk=4096)
-    # the default blocks are the tile: never shrunk at small V or T
-    assert _block_sizes(64, 64, TILE_1D, TILE_1D) == (TILE_1D, TILE_1D)
-    assert (vmem_footprint(cap, d, num_rows=64, t=64)
-            == vmem_footprint(cap, d))
-    # large extents keep the requested blocks
-    assert _block_sizes(1 << 20, 1 << 15, 4096, 2048) == (4096, 2048)
-    # blocks below the tile run in interpret mode only: the compiled path
-    # refuses them instead of handing Mosaic a mismatched layout
+    assert _block_sizes(64, 4096) == TILE_1D
+    assert not fits_vmem(cap, d, t_blk=4096)
+    assert fits_vmem(cap, d, t=64, t_blk=4096)
+    # the default tile is the 1-D tile: never shrunk at small T
+    assert _block_sizes(64, TILE_1D) == TILE_1D
+    assert vmem_footprint(cap, d, t=64) == vmem_footprint(cap, d)
+    # large extents keep the requested tile
+    assert _block_sizes(1 << 15, 2048) == 2048
+    # the one-hot is (t_blk, t_blk): the guard grows with the tile squared
+    assert (vmem_footprint(cap, d, t=1 << 15, t_blk=2048)
+            - vmem_footprint(cap, d, t=1 << 15)) > 3 * TILE_1D * TILE_1D * 4
+    # tiles below the 1-D tile run in interpret mode only: the compiled
+    # path refuses them instead of handing Mosaic a mismatched layout
     with pytest.raises(ValueError, match="1-D tile"):
         union_segsum(jnp.zeros((8,), jnp.int32), jnp.zeros((8, 2), jnp.float32),
-                     None, 1.0, 8, 64, v_blk=512, t_blk=512, interpret=False)
+                     None, 1.0, 8, t_blk=512, interpret=False)
 
 
 def test_union_segsum_grid_dims_sequential(monkeypatch):
-    """Regression: both grid dims of union_segsum are order-dependent (the
-    SMEM union-offset carry threads across vocab blocks), so the compiled
-    path must never declare a 'parallel' dim — reusing heat_scatter's
-    vocab-parallel default would corrupt the union on Megacore TPUs."""
+    """Regression: the one grid dim of union_segsum is order-dependent (a
+    segment that straddles two row tiles adds into the resident output
+    across them), so the compiled path must never declare it 'parallel' —
+    reusing heat_scatter's vocab-parallel default would corrupt the sums on
+    Megacore TPUs. The grid follows the rows, not the vocabulary."""
     import importlib
     hs_mod = importlib.import_module("repro.kernels.heat_scatter")
     us_mod = importlib.import_module("repro.kernels.union_segsum")
-    assert us_mod._DIM_SEMANTICS == ("arbitrary", "arbitrary")
+    assert us_mod._DIM_SEMANTICS == ("arbitrary",)
     cp = hs_mod._tpu_compiler_params(semantics=us_mod._DIM_SEMANTICS)
     assert isinstance(cp, us_mod.pltpu.CompilerParams)
-    assert tuple(cp.dimension_semantics) == ("arbitrary", "arbitrary")
+    assert tuple(cp.dimension_semantics) == ("arbitrary",)
     # heat_scatter's own default (independent vocab blocks) is unchanged
     cp_hs = hs_mod._tpu_compiler_params()
     assert tuple(cp_hs.dimension_semantics) == ("parallel", "arbitrary")
@@ -336,6 +377,7 @@ def test_union_segsum_grid_dims_sequential(monkeypatch):
     def interpreted_call(*args, **kw):
         seen["interpret"] = kw.get("interpret")
         seen["compiler_params"] = kw.pop("compiler_params", None)
+        seen.setdefault("grids", []).append(kw["grid"])
         kw["interpret"] = True
         return real_call(*args, **kw)
 
@@ -343,12 +385,17 @@ def test_union_segsum_grid_dims_sequential(monkeypatch):
     monkeypatch.setattr(us_mod.pl, "pallas_call", interpreted_call)
     ids = jnp.asarray([[0, 2, -1]], jnp.int32)
     rows = jnp.ones((1, 3, 4), jnp.float32)
-    u, _ = us_mod.union_segsum(ids, rows, None, 4.0, 4, 8, interpret=False)
+    u, _ = us_mod.union_segsum(ids, rows, None, 4.0, 4, interpret=False)
     assert seen["interpret"] is False
     assert seen["semantics"] == us_mod._DIM_SEMANTICS
     assert tuple(seen["compiler_params"].dimension_semantics) == \
         us_mod._DIM_SEMANTICS
     assert sorted(np.asarray(u)[np.asarray(u) >= 0].tolist()) == [0, 2]
+    # the grid follows the stacked rows: 2,500 of them run 3 row tiles
+    many = jnp.arange(2500, dtype=jnp.int32) % 97
+    us_mod.union_segsum(many, jnp.ones((2500, 4), jnp.float32), None, 4.0,
+                        97, interpret=False)
+    assert seen["grids"] == [(1,), (3,)]
 
 
 def test_union_segsum_scalar_params_do_not_retrace(rng):
@@ -361,7 +408,7 @@ def test_union_segsum_scalar_params_do_not_retrace(rng):
     rows = jnp.asarray(rng.normal(size=(1, 4, d)), jnp.float32)
     heat = jnp.ones((v,), jnp.float32)
     before = ops.union_segsum._cache_size()
-    outs = [ops.union_segsum(ids, rows, heat, total, 8, v, scale=scale)
+    outs = [ops.union_segsum(ids, rows, heat, total, 8, scale=scale)
             for total, scale in ((2.0, 1.0), (4.0, 1.0), (4.0, 0.5))]
     assert ops.union_segsum._cache_size() - before <= 1
     r0, r1, r2 = (np.asarray(r) for _, r in outs)

@@ -57,20 +57,33 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+#: ``union_segsum``'s ``vmem_footprint`` under its former vocabulary-blocked
+#: form (grid ``(V/1024, T/1024)``), at V = 2^20 and D = 25, by stacked rows
+#: T (the union capacity is T): the engine round (64 x 256) and the
+#: four-chip ``union`` combine's second pass (4 x 8,192).
+_SWEEP_FOOTPRINT = {T: 10_526_732, 4 * 8192: 12_230_668}
+
+
+@pytest.mark.parametrize("t", [T, 4 * 8192])
 @pytest.mark.parametrize("matmul_precision", ["default", "highest"])
-def test_union_segsum_compiles_for_v5e(one_chip, matmul_precision):
+def test_union_segsum_compiles_for_v5e(one_chip, matmul_precision, t):
     """The kernel states the precision of each of its matmuls, so a caller's
     ``jax.default_matmul_precision`` cannot hand Mosaic a contraction it
-    refuses (an fp32 contraction of bf16 operands)."""
-    from repro.kernels.union_segsum import union_segsum
+    refuses (an fp32 contraction of bf16 operands). Its guard prices no
+    more than the vocabulary-blocked kernel's did at the same shape, so
+    every union that compiled before still fits."""
+    from repro.kernels.union_segsum import fits_vmem, union_segsum, vmem_footprint
+
+    assert vmem_footprint(t, D, t=t) <= _SWEEP_FOOTPRINT[t]
+    assert fits_vmem(t, D, t=t)
 
     def fn(ids, rows, heat):
-        return union_segsum(ids, rows, heat, 128.0, T, V, scale=1.0 / K,
+        return union_segsum(ids, rows, heat, 128.0, t, scale=1.0 / K,
                             interpret=False)
 
     with jax.default_matmul_precision(matmul_precision):
-        text = _compiled_text(fn, _sds((T,), jnp.int32, one_chip),
-                              _sds((T, D), jnp.float32, one_chip),
+        text = _compiled_text(fn, _sds((t,), jnp.int32, one_chip),
+                              _sds((t, D), jnp.float32, one_chip),
                               _sds((V,), jnp.float32, one_chip))
     assert "tpu_custom_call" in text
 
